@@ -1,4 +1,7 @@
 import os
+# 512 emulated host devices: this tool only lowers and compiles, and must
+# never take an attached accelerator, so it pins JAX to the CPU.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 __doc__ = """§Perf confirmation experiment: per-layer collective wire bytes of
@@ -11,7 +14,6 @@ olmoe-1b-7b geometry, fwd+bwd of one MoE layer.
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
@@ -55,7 +57,7 @@ def run(verbose: bool = True):
                 xl.reshape(b * s, d), "model")
             return out.reshape(b, s, d), jax.lax.pmean(aux, "model")
 
-        mapped = shard_map(body, mesh=mesh,
+        mapped = jax.shard_map(body, mesh=mesh,
                            in_specs=(P(None, None), P("model", None, None),
                                      P("model", None, None), P("model", None, None),
                                      P("data", "model", None)),

@@ -1,4 +1,7 @@
 import os
+# 512 emulated host devices: this tool only lowers and compiles, and must
+# never take an attached accelerator, so it pins JAX to the CPU.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 __doc__ = """Roofline baseline table: per (arch x shape) on the single-pod

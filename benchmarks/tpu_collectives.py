@@ -7,10 +7,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import collectives as coll
+from repro.launch.mesh import make_mesh
 from .common import ClaimChecker, time_us
 
 
@@ -20,7 +20,7 @@ def run(verbose: bool = True):
     x = jax.random.normal(jax.random.PRNGKey(0), (n * 8, 128), jnp.float32)
 
     def wrap(fn):
-        return jax.jit(shard_map(lambda a: fn(a, "x"), mesh=mesh,
+        return jax.jit(jax.shard_map(lambda a: fn(a, "x"), mesh=mesh,
                                  in_specs=P("x", None), out_specs=P(None, None, None),
                                  check_vma=False))
 

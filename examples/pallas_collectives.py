@@ -1,9 +1,12 @@
-"""Run the Pallas remote-DMA collective kernels (ring all-gather with
-pcpy/b2b/bcst sync variants; swap/b2b all-to-all) on 8 emulated devices in
-interpret mode and validate against the pure-jnp oracles.
+"""CPU-only demo: run the Pallas remote-DMA collective kernels (ring
+all-gather with pcpy/b2b/bcst sync variants; swap/b2b all-to-all) on 8
+emulated CPU devices in Pallas TPU interpret mode and validate against the
+pure-jnp oracles.
 
-Re-executes itself with XLA_FLAGS=--xla_force_host_platform_device_count=8
-if needed (jax locks the device count at first init).
+Re-executes itself with JAX_PLATFORMS=cpu and
+XLA_FLAGS=--xla_force_host_platform_device_count=8 (jax locks the platform
+and device count at first init), so it never takes an attached TPU.  On a
+TPU host, ``python chip_smoke.py --chips 4`` runs the same kernels compiled.
 
     PYTHONPATH=src python examples/pallas_collectives.py
 """
@@ -16,6 +19,7 @@ N = 8
 if os.environ.get("_REPRO_PALLAS_CHILD") != "1":
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["_REPRO_PALLAS_CHILD"] = "1"
     raise SystemExit(subprocess.call([sys.executable, os.path.abspath(__file__)], env=env))
 
@@ -23,14 +27,7 @@ import jax                      # noqa: E402
 import jax.numpy as jnp         # noqa: E402
 import numpy as np              # noqa: E402
 
-from jax.experimental.pallas import tpu as _pltpu   # noqa: E402
-if not hasattr(_pltpu, "InterpretParams"):
-    raise SystemExit(
-        "these remote-DMA kernels need real TPUs or the pallas TPU interpret "
-        "mode (jax >= 0.5); this jax's generic interpreter has no CPU "
-        "lowering for TPU semaphore primitives")
-
-from repro.compat import make_mesh                                 # noqa: E402
+from repro.launch.mesh import make_mesh                            # noqa: E402
 from repro.kernels.ring_all_gather.ops import ring_all_gather      # noqa: E402
 from repro.kernels.ring_all_gather.ref import all_gather_ref       # noqa: E402
 from repro.kernels.ring_all_to_all.ops import pallas_all_to_all    # noqa: E402

@@ -10,7 +10,6 @@ the local device mesh.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh, shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.dma import (
@@ -19,6 +18,7 @@ from repro.core.dma import (
 )
 from repro.core.dma.rccl_model import rccl_collective_latency
 from repro.core import collectives as coll
+from repro.launch.mesh import make_mesh
 
 KB, MB = 1024, 1024 * 1024
 
@@ -44,7 +44,7 @@ def main():
     n = len(jax.devices())
     mesh = make_mesh((n,), ("x",))
     x = jax.random.normal(jax.random.PRNGKey(0), (n * 4, 32), jnp.float32)
-    ring = jax.jit(shard_map(lambda a: coll.ring_all_gather(a, "x").reshape(-1, a.shape[-1]),
+    ring = jax.jit(jax.shard_map(lambda a: coll.ring_all_gather(a, "x").reshape(-1, a.shape[-1]),
                              mesh=mesh, in_specs=P("x", None),
                              out_specs=P(None, None), check_vma=False))
     ok = np.allclose(np.asarray(ring(x)), np.asarray(x))

@@ -174,6 +174,10 @@ _AR_IMPL = {
     "hier_pipe_rs": coll.ring_all_reduce,
 }
 
+# Position of each collective's table in the (ag, aa, rs, ar) tuple.
+_TABLE_INDEX = {"all_gather": 0, "all_to_all": 1, "reduce_scatter": 2,
+                "all_reduce": 3}
+
 
 def _derive_single_node(topo: Topology):
     """Derive the (ag, aa, rs, ar) latte tables for one single-node topology.
@@ -328,23 +332,30 @@ class CommBackend:
                 StaleTablesWarning, stacklevel=3)
         return tpu_dispatch_tables(self.axis_devices)
 
+    def _dispatch(self, collective: str, impls: dict, x, axis_name: str,
+                  size: int):
+        """Run the table's winner at ``size`` bytes.  A winner with no JAX
+        implementation is an error, never a silent fall back to XLA."""
+        table = self._tables(collective)[_TABLE_INDEX[collective]]
+        variant = self._strip(_pick(table, size))
+        if variant not in impls:
+            raise ValueError(f"CommBackend('latte').{collective}: dispatch "
+                             f"winner {variant!r} has no JAX implementation")
+        return impls[variant](x, axis_name)
+
     def all_gather(self, x, axis_name: str):
         """Called inside shard_map.  Returns stacked [n, *x.shape]."""
         if self.kind == "reference":
             return coll.reference_all_gather(x, axis_name)
         size = x.size * x.dtype.itemsize * self.axis_devices
-        ag = self._tables("all_gather")[0]
-        variant = self._strip(_pick(ag, size))
-        return _AG_IMPL.get(variant, coll.reference_all_gather)(x, axis_name)
+        return self._dispatch("all_gather", _AG_IMPL, x, axis_name, size)
 
     def all_to_all(self, x, axis_name: str):
         """Called inside shard_map with x: [n, ...] chunks."""
         if self.kind == "reference":
             return coll.reference_all_to_all(x, axis_name)
         size = x.size * x.dtype.itemsize
-        aa = self._tables("all_to_all")[1]
-        variant = self._strip(_pick(aa, size))
-        return _AA_IMPL.get(variant, coll.reference_all_to_all)(x, axis_name)
+        return self._dispatch("all_to_all", _AA_IMPL, x, axis_name, size)
 
     def reduce_scatter(self, x, axis_name: str):
         """Called inside shard_map with x: [n, ...] addend chunks; returns
@@ -352,9 +363,7 @@ class CommBackend:
         if self.kind == "reference":
             return coll.reference_reduce_scatter(x, axis_name)
         size = x.size * x.dtype.itemsize
-        rs = self._tables("reduce_scatter")[2]
-        variant = self._strip(_pick(rs, size))
-        return _RS_IMPL.get(variant, coll.reference_reduce_scatter)(x, axis_name)
+        return self._dispatch("reduce_scatter", _RS_IMPL, x, axis_name, size)
 
     def all_reduce(self, x, axis_name: str):
         """Called inside shard_map with x: [n, ...] chunks; returns the
@@ -362,9 +371,7 @@ class CommBackend:
         if self.kind == "reference":
             return coll.reference_all_reduce(x, axis_name)
         size = x.size * x.dtype.itemsize
-        ar = self._tables("all_reduce")[3]
-        variant = self._strip(_pick(ar, size))
-        return _AR_IMPL.get(variant, coll.reference_all_reduce)(x, axis_name)
+        return self._dispatch("all_reduce", _AR_IMPL, x, axis_name, size)
 
     def kv_fetch_plan(self, n_blocks: int, block_bytes: int) -> dict:
         """How the serving engine should fetch dispersed KV blocks (§5.3).

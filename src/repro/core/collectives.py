@@ -19,12 +19,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     """b2b analogue.  x: local shard -> [n, *x.shape] gathered (stacked)."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     chunks = [x]
@@ -39,7 +37,7 @@ def ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
 
 def bidir_ring_all_gather(x: jax.Array, axis_name: str) -> jax.Array:
     """bcst analogue: both directions each step, ceil((n-1)/2) steps."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     fwd_perm = [(i, (i + 1) % n) for i in range(n)]
     bwd_perm = [(i, (i - 1) % n) for i in range(n)]
@@ -64,7 +62,7 @@ def pairwise_all_to_all(x: jax.Array, axis_name: str) -> jax.Array:
     Round r exchanges chunk x[idx^r] with partner idx^r (n power of two), a
     symmetric in-place pairwise swap; falls back to rotation pairing else.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     assert x.shape[0] == n
     power_of_two = (n & (n - 1)) == 0
@@ -104,7 +102,7 @@ def ring_reduce_scatter(x: jax.Array, axis_name: str) -> jax.Array:
     sending) the partial destined for ``(i - r - 1) % n``.  This is the
     ppermute rendering of the ``ring_rs`` DMA schedule.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     acc = jnp.take(x, jnp.mod(idx - 1, n), axis=0)

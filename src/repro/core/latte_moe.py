@@ -24,7 +24,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 import numpy as np
 
 from repro.configs.base import ArchConfig
@@ -50,7 +49,7 @@ def latte_moe_local(cfg: ArchConfig, p: dict, xf: jax.Array, axis_name: str,
     E, K = m.n_experts, m.top_k
     T, D = xf.shape
     C = _local_capacity(cfg, T)
-    n_shards = axis_size(axis_name)
+    n_shards = jax.lax.axis_size(axis_name)
     e_local = E // n_shards
     cd = xf.dtype
 
@@ -102,8 +101,6 @@ def make_latte_moe(cfg: ArchConfig, mesh, axis_name: str, *, all_to_all=None):
     expert weights sharded on the expert dim."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     assert cfg.moe and cfg.moe.n_experts % mesh.shape[axis_name] == 0
 
     def fn(p, x):
@@ -116,7 +113,7 @@ def make_latte_moe(cfg: ArchConfig, mesh, axis_name: str, *, all_to_all=None):
                 xl.reshape(b * s, d), axis_name, all_to_all=all_to_all)
             return out.reshape(b, s, d), jax.lax.pmean(aux, axis_name)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(None, None), P(axis_name, None, None),
                       P(axis_name, None, None), P(axis_name, None, None),

@@ -7,9 +7,12 @@ single kernel walks the dispersed blocks directly (one grid step per block —
 the analogue of one workgroup per KV block).
 
 Grid: (batch, kv_heads, max_blocks); scalar-prefetch operands are the block
-table and per-sequence lengths.  VMEM scratch carries the running max /
-normalizer / accumulator across the block axis (grid iterates row-major, so
-the block axis is innermost).
+table and per-sequence lengths.  The pools are laid out head-major,
+``[n_pool, KV, bt, hd]``, so one K/V block is a ``(bt, hd)`` tile: the TPU
+compiler needs the last two dims of a block to be (8, 128)-aligned or whole,
+which a ``(1, hd)`` slice of a token-major pool is not.  VMEM scratch
+carries the running max / normalizer / accumulator across the block axis
+(grid iterates row-major, so the block axis is innermost).
 """
 from __future__ import annotations
 
@@ -27,8 +30,8 @@ def _decode_kernel(
     tbl_ref,      # [B, max_blocks] int32 (scalar prefetch)
     len_ref,      # [B] int32 (scalar prefetch)
     q_ref,        # [1, 1, G, hd]
-    k_ref,        # [1, bt, 1, hd]
-    v_ref,        # [1, bt, 1, hd]
+    k_ref,        # [1, 1, bt, hd]
+    v_ref,        # [1, 1, bt, hd]
     o_ref,        # [1, 1, G, hd]
     m_scr,        # [G, 1] f32
     l_scr,        # [G, 1] f32
@@ -54,8 +57,8 @@ def _decode_kernel(
     @pl.when(base < length)
     def _():
         q = q_ref[0, 0].astype(jnp.float32)                    # [G, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)                 # [bt, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)                 # [bt, hd]
+        k = k_ref[0, 0].astype(jnp.float32)                    # [bt, hd]
+        v = v_ref[0, 0].astype(jnp.float32)                    # [bt, hd]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [G, bt]
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
@@ -78,8 +81,8 @@ def _decode_kernel(
 
 def paged_decode_attention(
     q: jax.Array,             # [B, KV, G, hd] (grouped query heads)
-    k_pool: jax.Array,        # [n_pool, bt, KV, hd]
-    v_pool: jax.Array,        # [n_pool, bt, KV, hd]
+    k_pool: jax.Array,        # [n_pool, KV, bt, hd]
+    v_pool: jax.Array,        # [n_pool, KV, bt, hd]
     block_tables: jax.Array,  # [B, max_blocks] int32
     lengths: jax.Array,       # [B] int32
     *,
@@ -88,7 +91,7 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Returns attention output [B, KV, G, hd]."""
     B, KV, G, hd = q.shape
-    _, bt, _, _ = k_pool.shape
+    bt = k_pool.shape[2]
     max_blocks = block_tables.shape[1]
     scale = 1.0 / (hd ** 0.5)
 
@@ -97,8 +100,8 @@ def paged_decode_attention(
         grid=(B, KV, max_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, bt, 1, hd), lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
-            pl.BlockSpec((1, bt, 1, hd), lambda b, h, j, tbl, ln: (tbl[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, bt, hd), lambda b, h, j, tbl, ln: (tbl[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, bt, hd), lambda b, h, j, tbl, ln: (tbl[b, j], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j, tbl, ln: (b, h, 0, 0)),
         scratch_shapes=[

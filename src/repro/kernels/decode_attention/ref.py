@@ -6,17 +6,17 @@ import jax.numpy as jnp
 
 def paged_decode_attention_ref(q, k_pool, v_pool, block_tables, lengths,
                                *, softcap=None):
-    """q [B,KV,G,hd]; pools [n,bt,KV,hd]; tables [B,max_blocks]; lengths [B]."""
+    """q [B,KV,G,hd]; pools [n,KV,bt,hd]; tables [B,max_blocks]; lengths [B]."""
     B, KV, G, hd = q.shape
-    _, bt, _, _ = k_pool.shape
+    bt = k_pool.shape[2]
     max_blocks = block_tables.shape[1]
     scale = 1.0 / (hd ** 0.5)
     outs = []
     for b in range(B):
-        k = jnp.take(k_pool, block_tables[b], axis=0)   # [mb, bt, KV, hd]
+        k = jnp.take(k_pool, block_tables[b], axis=0)   # [mb, KV, bt, hd]
         v = jnp.take(v_pool, block_tables[b], axis=0)
-        k = k.reshape(max_blocks * bt, KV, hd)
-        v = v.reshape(max_blocks * bt, KV, hd)
+        k = jnp.swapaxes(k, 1, 2).reshape(max_blocks * bt, KV, hd)
+        v = jnp.swapaxes(v, 1, 2).reshape(max_blocks * bt, KV, hd)
         s = jnp.einsum("kgd,skd->kgs", q[b].astype(jnp.float32),
                        k.astype(jnp.float32)) * scale
         if softcap:

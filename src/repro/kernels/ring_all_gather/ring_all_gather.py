@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pltpu_compiler_params, pltpu_interpret_mode
-
 
 def _neighbors(axis_name: str, n: int):
     my = jax.lax.axis_index(axis_name)
@@ -170,8 +168,8 @@ def make_ring_all_gather(
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[pltpu.SemaphoreType.DMA]
             + [pltpu.SemaphoreType.DMA((n_steps,))] * 4,
-            compiler_params=pltpu_compiler_params(collective_id=collective_id),
-            interpret=pltpu_interpret_mode() if interpret else False,
+            compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+            interpret=pltpu.InterpretParams() if interpret else False,
         )(chunk)
         return out.reshape(num_devices * c, f)
 

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pltpu_compiler_params, pltpu_interpret_mode
-
 
 def all_to_all_kernel(
     x_ref,          # [n, chunk, F] input chunks (ANY)
@@ -46,10 +44,15 @@ def all_to_all_kernel(
     n = num_devices
     my = jax.lax.axis_index(axis_name)
 
+    # Every device writes into every other one, so the ready barrier spans
+    # all peers (XOR partners are not ring neighbours for n > 2).
     barrier = pltpu.get_barrier_semaphore()
-    for d in (jax.lax.rem(my + 1, n), jax.lax.rem(my + n - 1, n)):
-        pltpu.semaphore_signal(barrier, 1, device_id=d)
-    pltpu.semaphore_wait(barrier, 2)
+
+    def signal(r, _):
+        pltpu.semaphore_signal(barrier, 1, device_id=jax.lax.rem(my + r, n))
+        return 0
+    jax.lax.fori_loop(1, n, signal, 0)
+    pltpu.semaphore_wait(barrier, n - 1)
 
     local = pltpu.make_async_copy(x_ref.at[my], out_ref.at[my], local_sem)
     local.start()
@@ -113,8 +116,8 @@ def make_all_to_all(
             scratch_shapes=[pltpu.SemaphoreType.DMA,
                             pltpu.SemaphoreType.DMA((n_steps,)),
                             pltpu.SemaphoreType.DMA((n_steps,))],
-            compiler_params=pltpu_compiler_params(collective_id=collective_id),
-            interpret=pltpu_interpret_mode() if interpret else False,
+            compiler_params=pltpu.CompilerParams(collective_id=collective_id),
+            interpret=pltpu.InterpretParams() if interpret else False,
         )(x)
 
     return fn

@@ -1,4 +1,7 @@
 import os
+# 512 emulated host devices: this tool only lowers and compiles, and must
+# never take an attached accelerator, so it pins JAX to the CPU.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 __doc__ = """Multi-pod dry-run: prove the distribution config is coherent.
@@ -28,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import cost_analysis_dict
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config, get_shape
 from repro.models import build_model
 from repro.models.transformer import Model
@@ -42,6 +44,7 @@ from repro.sharding.rules import (
 )
 from repro.train.loop import make_train_step
 from repro.train.optimizer import AdamWConfig, init_opt_state
+from .compile_cache import enable_compile_cache
 from .input_specs import input_specs, skip_reason
 from .mesh import dp_axes, make_production_mesh
 
@@ -149,7 +152,7 @@ def run_one(arch_id: str, shape_id: str, *, multi_pod: bool = False,
             lowered = jitted.lower(*args)
             compiled = lowered.compile()
         dt = time.perf_counter() - t0
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         mem = compiled.memory_analysis()
         counts: dict[str, int] = {}
         try:
@@ -193,6 +196,7 @@ def main() -> int:
     ap.add_argument("--both-meshes", action="store_true")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     pairs = ([(a, s) for a in ARCH_IDS for s in INPUT_SHAPES]
              if args.all else [(args.arch, args.shape)])

@@ -1,56 +1,138 @@
 """Serving driver: batched requests with host-memory context caching,
 comparing KV-fetch backends (the paper's §5.3 workload).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b --batch 4 --ctx 128
+Serves the architecture at its published widths with seeded random
+weights: a batch of requests first misses (prefill, then save to the host
+store), then hits through every fetch backend.  It checks that each backend
+fetches exactly the bytes that were saved, that all hit backends decode the
+same tokens, and that the hit path's first-token logits agree with the miss
+path's.  Times are host-clock wall times on the device JAX runs on; a
+warm-up pass on other keys compiles every program first.
+
+    python -m repro.launch.serve                      # qwen2-0.5b, 8 x 1024 tokens
+    JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced --batch 2 --ctx 64
+
+(with ``PYTHONPATH=src``).  ``--reduced`` serves the toy preset of the same
+family (width <= 256, <= 2 layers), for the CPU.
 """
 from __future__ import annotations
 
 import argparse
+import time
 
 import numpy as np
 
 import jax
 
 from repro.configs import ARCH_IDS, get_config
+from repro.configs.base import ArchConfig
 from repro.models import build_model
 from repro.serve.engine import ServeEngine
+from .compile_cache import compile_stats, enable_compile_cache
+
+FETCH_BACKENDS = ("pcpy", "b2b", "opt_b2b", "kernel")
+
+# Hit and miss compute the prompt's last position in differently shaped
+# bf16 programs (one decode step vs the whole-prompt prefill), which round
+# at different points in each of the layers.  That moves the logits by a
+# few bf16 ulps (2^-8 relative) per layer; a cache holding the wrong
+# tokens, positions or blocks moves them by O(1).
+LOGIT_REL_TOL = 5e-2
+
+
+def _bit_equal(a: np.ndarray, b: np.ndarray) -> bool:
+    return a.dtype == b.dtype and a.shape == b.shape and a.tobytes() == b.tobytes()
+
+
+def _describe(cfg: ArchConfig) -> str:
+    return (f"{cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
+            f"{cfg.n_heads} heads ({cfg.n_kv_heads} KV, head_dim {cfg.head_dim}), "
+            f"d_ff {cfg.d_ff}, vocab {cfg.vocab}, {cfg.n_params / 1e6:.0f}M params")
+
+
+def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
+        log=print) -> None:
+    """Serve ``batch`` requests of ``ctx`` prompt tokens and ``new`` decoded
+    tokens as misses, then as hits through every fetch backend, and check
+    the results.  Raises AssertionError if a check fails."""
+    model = build_model(cfg)
+    eng = ServeEngine(model, jax.jit(model.init)(jax.random.PRNGKey(seed)))
+    rng = np.random.default_rng(seed)
+    dev = jax.devices()[0]
+    where = f"wall, {dev.platform}:{dev.device_kind}"
+    log(f"[serve] {_describe(cfg)}")
+    log(f"[serve] {batch} requests x {ctx} prompt tokens, {new} new tokens each, "
+        f"fetch backends {', '.join(FETCH_BACKENDS)}")
+
+    def one_pass(tag: str):
+        prompts = rng.integers(0, cfg.vocab, (batch, ctx)).astype(np.int32)
+        keys = [f"{tag}-{i}" for i in range(batch)]
+        miss = eng.generate(prompts, keys, new)
+        hits = {b: eng.generate(prompts, keys, new, fetch_backend=b)
+                for b in FETCH_BACKENDS}
+        return keys, miss, hits
+
+    t0 = time.perf_counter()
+    one_pass("warmup")
+    warmup_s = time.perf_counter() - t0
+    cs = compile_stats()
+    log(f"[serve] warm-up pass on other keys: {warmup_s:.3f} s ({where}); "
+        f"compilation so far: {cs['compiles']} programs, {cs['compile_s']:.3f} s, "
+        f"{cs['cache_hits']} persistent-cache hits")
+
+    keys, miss, hits = one_pass("req")
+    assert not miss.request_stats[0].cache_hit
+    st = miss.request_stats[0]
+    log(f"[miss/prefill ] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
+        f"{miss.tokens_per_s_wall:.1f} tok/s ({where})")
+    for b, res in hits.items():
+        st = res.request_stats[0]
+        assert st.cache_hit, b
+        log(f"[hit/{b:10s}] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
+            f"{res.tokens_per_s_wall:.1f} tok/s ({where}); {st.n_transfers} "
+            f"transfers, modeled MI300X fetch {st.fetch_modeled_s * 1e6:.1f} us")
+
+    for b in FETCH_BACKENDS:
+        for key in keys:
+            kb, vb = eng.store.saved(key)
+            got = eng.store.fetch(key, b)
+            assert _bit_equal(got.k_blocks, kb) and _bit_equal(got.v_blocks, vb), \
+                f"{b} fetched K/V that differ from what {key} saved"
+    log(f"[check] fetched K/V bit-identical to the saved K/V: every backend, "
+        f"{len(keys)} requests, {kb.nbytes + vb.nbytes} bytes each")
+
+    ref_tokens = hits[FETCH_BACKENDS[0]].tokens
+    for b, res in hits.items():
+        assert np.array_equal(res.tokens, ref_tokens), f"{b} decoded other tokens"
+    log(f"[check] decoded tokens identical across hit backends "
+        f"({ref_tokens.shape[0]} x {ref_tokens.shape[1]} tokens)")
+
+    hit, ref = hits[FETCH_BACKENDS[0]].first_logits, miss.first_logits
+    assert np.isfinite(hit).all() and np.isfinite(ref).all(), "non-finite logits"
+    rel = float(np.max(np.linalg.norm(hit - ref, axis=-1)
+                       / np.linalg.norm(ref, axis=-1)))
+    agree = int(np.sum(hit.argmax(-1) == ref.argmax(-1)))
+    assert rel <= LOGIT_REL_TOL, f"hit vs miss logits: relative error {rel} > {LOGIT_REL_TOL}"
+    log(f"[check] hit vs miss first-token logits: worst relative L2 error "
+        f"{rel:.3e} <= {LOGIT_REL_TOL} (bf16); argmax agrees for {agree}/{batch}")
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="deepseek-7b", choices=list(ARCH_IDS))
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--ctx", type=int, default=128)
+    ap.add_argument("--arch", default="qwen2-0.5b", choices=list(ARCH_IDS))
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the toy preset of the family (CPU use)")
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--ctx", type=int, default=1024)
     ap.add_argument("--new", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).reduced()
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
-    try:
-        eng = ServeEngine(model, params)
-    except ValueError as e:
-        raise SystemExit(
-            f"{args.arch} is not servable by this engine ({e}); "
-            "use a decoder-LM arch with uniform layers, e.g. deepseek-7b, "
-            "qwen2-0.5b, mixtral-8x7b, olmoe-1b-7b")
-    rng = np.random.default_rng(args.seed)
-    prompts = rng.integers(0, cfg.vocab, (args.batch, args.ctx)).astype(np.int32)
-    keys = [f"req-{i}" for i in range(args.batch)]
-
-    print(f"== {cfg.name}: {args.batch} requests x {args.ctx} ctx, {args.new} new tokens ==")
-    res_miss = eng.generate(prompts, keys, args.new)
-    print(f"[miss/prefill] ttft_wall={res_miss.request_stats[0].ttft_wall_s*1e3:.1f}ms "
-          f"tok/s={res_miss.tokens_per_s_wall:.1f}")
-    for backend in ("pcpy", "b2b", "opt_b2b", "kernel"):
-        res = eng.generate(prompts, keys, args.new, fetch_backend=backend)
-        st = res.request_stats[0]
-        same = (res.tokens == res_miss.tokens).all()
-        print(f"[hit/{backend:6s}] ttft_wall={st.ttft_wall_s*1e3:.1f}ms "
-              f"fetch_modeled={st.fetch_modeled_s*1e6:.1f}us transfers={st.n_transfers} "
-              f"tok/s={res.tokens_per_s_wall:.1f} tokens_match={same}")
-        assert same, f"{backend} produced different tokens"
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    run(cfg, batch=args.batch, ctx=args.ctx, new=args.new, seed=args.seed)
 
 
 if __name__ == "__main__":

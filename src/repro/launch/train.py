@@ -18,6 +18,7 @@ from repro.models import build_model
 from repro.train.checkpoint import save_checkpoint
 from repro.train.loop import train_loop
 from repro.train.optimizer import AdamWConfig
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)
     if cfg.family in ("vlm",):

@@ -24,7 +24,6 @@ from typing import Callable
 
 import jax
 
-from repro.compat import cost_analysis_dict
 from repro.configs import get_config, get_shape
 from repro.configs.base import ArchConfig
 from repro.models import attention as attn_mod
@@ -92,7 +91,7 @@ def _measure(arch_id: str, shape_id: str, mesh, cfg: ArchConfig, perf=None) -> C
         fn, args, in_sh, out_sh = built
         with mesh:
             compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh).lower(*args).compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     wire = wire_bytes_by_kind(compiled.as_text())
     return CostVector(float(ca.get("flops", 0.0)),
                       float(ca.get("bytes accessed", 0.0)), wire)

@@ -13,9 +13,10 @@ Serving flow (mirrors the paper's vLLM + KV-offload setup, §5.3):
 2. Decode proceeds in batched steps over all active sequences.
 
 TTFT therefore = fetch(+rebuild) time on hits vs prefill time on misses —
-exactly the quantity Figures 16/17 study.  Wall-clock numbers on this CPU
-container are functional only; the calibrated DMA model supplies the
-transfer-side latencies for the paper-scale benchmarks.
+exactly the quantity Figures 16/17 study.  Wall times are host-clock spans
+that end in a device-to-host copy of the result (so the device has
+finished); they describe whatever backend ran them.  ``fetch_modeled_s`` is
+the MI300X DMA model's prediction, not a measurement.
 
 Concurrent-traffic serving (DESIGN.md §12): :class:`ServingSimulator` is
 the *modeled* counterpart for load studies — a continuous-batching loop
@@ -47,8 +48,8 @@ from .kvcache import BLOCK_TOKENS, blocks_to_kv, kv_to_blocks
 class RequestStats:
     key: str
     cache_hit: bool
-    ttft_wall_s: float
-    fetch_modeled_s: float      # 0 on miss
+    ttft_wall_s: float          # the batch's wall time to its first tokens
+    fetch_modeled_s: float      # MI300X model, not measured; 0 on miss
     n_transfers: int
     prompt_tokens: int
 
@@ -56,6 +57,7 @@ class RequestStats:
 @dataclasses.dataclass
 class GenerationResult:
     tokens: np.ndarray          # [B, n_new]
+    first_logits: np.ndarray    # [B, vocab] f32 logits of the first new token
     request_stats: list[RequestStats]
     decode_wall_s: float
     tokens_per_s_wall: float
@@ -109,8 +111,8 @@ class ServeEngine:
                     *, fetch_backend: str | None = None,
                     capacity: int | None = None):
         """TTFT path for a batch sharing prompt length.  Returns
-        (first_tokens [B], cache, stats).  ``fetch_backend=None`` follows
-        the CommBackend's ``kv_fetch_plan``."""
+        (first_tokens [B], first_logits [B, vocab] f32, cache, stats).
+        ``fetch_backend=None`` follows the CommBackend's ``kv_fetch_plan``."""
         B, S = prompts.shape
         capacity = capacity or S + 64
         all_hit = all(k in self.store for k in keys)
@@ -137,7 +139,7 @@ class ServeEngine:
             first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             wall = time.perf_counter() - t0
             for key in keys:
-                stats.append(RequestStats(key, True, wall / B, modeled_total / B,
+                stats.append(RequestStats(key, True, wall, modeled_total / B,
                                           n_tr, S))
         else:
             logits, k, v = self._prefill(jnp.asarray(prompts))
@@ -146,17 +148,17 @@ class ServeEngine:
             for b, key in enumerate(keys):
                 kb, vb = kv_to_blocks(k[:, b:b + 1], v[:, b:b + 1], self.block_tokens)
                 self.store.save(key, kb, vb, S)
-                stats.append(RequestStats(key, False, wall / B, 0.0, 0, S))
+                stats.append(RequestStats(key, False, wall, 0.0, 0, S))
             cache = self._build_cache(k, v, capacity)
-        return first, cache, stats
+        first_logits = np.asarray(logits[:, -1], np.float32)
+        return first, first_logits, cache, stats
 
     def generate(self, prompts: np.ndarray, keys: Sequence[str], n_new: int,
                  *, fetch_backend: str | None = None) -> GenerationResult:
         B, S = prompts.shape
         capacity = S + n_new + 1
-        first, cache, stats = self.first_token(prompts, keys,
-                                               fetch_backend=fetch_backend,
-                                               capacity=capacity)
+        first, first_logits, cache, stats = self.first_token(
+            prompts, keys, fetch_backend=fetch_backend, capacity=capacity)
         toks = [first]
         cur = jnp.asarray(first)[:, None]
         t0 = time.perf_counter()
@@ -167,7 +169,8 @@ class ServeEngine:
             toks.append(np.asarray(cur)[:, 0])
         dt = time.perf_counter() - t0
         tokens = np.stack(toks, axis=1)
-        return GenerationResult(tokens, stats, dt, B * (n_new - 1) / max(dt, 1e-9))
+        return GenerationResult(tokens, first_logits, stats, dt,
+                                B * (n_new - 1) / max(dt, 1e-9))
 
 
 # ===================================================================== #

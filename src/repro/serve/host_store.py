@@ -18,9 +18,10 @@ re-running prefill.  Three fetch backends mirror the paper's comparison:
                (repro/kernels/paged_kv_gather) reassembles dispersed blocks
                on device (the CU/workgroup-per-block alternative).
 
-Each fetch also returns the MODELED DMA latency from the calibrated engine
-model (the container has no PCIe to measure), which the TTFT/throughput
-benchmarks consume; the data path itself is real and correctness-checked.
+Each fetch also returns a MODELED latency from the calibrated MI300X engine
+model, which the simulator's TTFT/throughput benchmarks consume.  It is a
+prediction for that platform, never a measurement of the device the data
+path ran on; the data path itself is real and correctness-checked.
 """
 from __future__ import annotations
 
@@ -41,7 +42,7 @@ class FetchResult:
     k_blocks: np.ndarray        # [n_blocks, bt, L, KV, hd]
     v_blocks: np.ndarray
     n_transfers: int
-    modeled_seconds: float      # calibrated DMA/kernel model latency
+    modeled_seconds: float      # MI300X DMA/kernel model latency (not measured)
 
 
 class HostKVStore:
@@ -57,6 +58,11 @@ class HostKVStore:
 
     def __contains__(self, key: str) -> bool:
         return key in self._store
+
+    def saved(self, key: str) -> tuple[np.ndarray, np.ndarray]:
+        """The (k_blocks, v_blocks) host arrays saved under ``key``."""
+        kb, vb, _ = self._store[key]
+        return kb, vb
 
     def tokens_for(self, key: str) -> int:
         return self._store[key][2]
@@ -97,13 +103,16 @@ class HostKVStore:
             modeled = simulate(sched, self.topo).latency
             n_transfers = 1
         elif backend == "kernel":
-            # move the pool once; Pallas kernel gathers dispersed blocks
+            # move the pool once; Pallas kernel gathers dispersed blocks.
+            # It runs compiled on an accelerator; only the CPU backend (the
+            # test suite) runs it through the Pallas interpreter.
             from repro.kernels.paged_kv_gather.ops import gather_blocks
+            interpret = jax.default_backend() == "cpu"
             pool_k = jax.device_put(kb.reshape(n_blocks, self.block_tokens, -1))
             pool_v = jax.device_put(vb.reshape(n_blocks, self.block_tokens, -1))
             tbl = jnp.arange(n_blocks, dtype=jnp.int32)
-            k_out = np.asarray(gather_blocks(pool_k, tbl, interpret=True)).reshape(kb.shape)
-            v_out = np.asarray(gather_blocks(pool_v, tbl, interpret=True)).reshape(vb.shape)
+            k_out = np.asarray(gather_blocks(pool_k, tbl, interpret=interpret)).reshape(kb.shape)
+            v_out = np.asarray(gather_blocks(pool_v, tbl, interpret=interpret)).reshape(vb.shape)
             modeled = kernel_copy_latency(self.topo, n_blocks * block_bytes, n_launches=1)
             n_transfers = 1
         else:

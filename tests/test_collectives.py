@@ -13,7 +13,7 @@ from repro.core.backend import (CommBackend, StaleTablesWarning,
 LATTE_TEST = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.core import collectives as coll
 from repro.core.backend import CommBackend
 
@@ -22,7 +22,7 @@ mesh = make_mesh((N,), ("x",))
 
 x = jax.random.normal(jax.random.PRNGKey(0), (N, 4, 32), jnp.float32)
 def wrap_ag(fn):
-    f = shard_map(lambda a: fn(a[0], "x"), mesh=mesh, in_specs=P("x", None, None),
+    f = jax.shard_map(lambda a: fn(a[0], "x"), mesh=mesh, in_specs=P("x", None, None),
                   out_specs=P(None, None, None), check_vma=False)
     return np.asarray(jax.jit(f)(x))
 ref = np.asarray(x)
@@ -33,7 +33,7 @@ for name, fn in (("ring", coll.ring_all_gather),
 
 xa = jax.random.normal(jax.random.PRNGKey(1), (N, N, 2, 16), jnp.float32)
 def wrap_aa(fn):
-    f = shard_map(lambda a: fn(a[0], "x")[None], mesh=mesh,
+    f = jax.shard_map(lambda a: fn(a[0], "x")[None], mesh=mesh,
                   in_specs=P("x", None, None, None),
                   out_specs=P("x", None, None, None), check_vma=False)
     return np.asarray(jax.jit(f)(xa))
@@ -45,14 +45,14 @@ assert np.allclose(wrap_aa(coll.reference_all_to_all), expect)
 xr = jax.random.normal(jax.random.PRNGKey(2), (N, N, 2, 8), jnp.float32)
 expect_rs = np.asarray(xr).sum(axis=0)          # row i = device i's chunk
 def wrap_rs(fn):
-    f = shard_map(lambda a: fn(a[0], "x")[None], mesh=mesh,
+    f = jax.shard_map(lambda a: fn(a[0], "x")[None], mesh=mesh,
                   in_specs=P("x", None, None, None),
-                  out_specs=P("x", None, None, None), check_vma=False)
+                  out_specs=P("x", None, None), check_vma=False)
     return np.asarray(jax.jit(f)(xr))
 assert np.allclose(wrap_rs(coll.ring_reduce_scatter), expect_rs, atol=1e-4)
 assert np.allclose(wrap_rs(coll.reference_reduce_scatter), expect_rs, atol=1e-4)
 def wrap_ar(fn):
-    f = shard_map(lambda a: fn(a[0], "x"), mesh=mesh,
+    f = jax.shard_map(lambda a: fn(a[0], "x"), mesh=mesh,
                   in_specs=P("x", None, None, None),
                   out_specs=P(None, None, None), check_vma=False)
     return np.asarray(jax.jit(f)(xr))
@@ -63,15 +63,15 @@ assert np.allclose(wrap_ar(coll.reference_all_reduce), expect_rs, atol=1e-4)
 # acknowledgment keeps the subprocess log warning-free (test_backend covers
 # the warning itself).
 be = CommBackend("latte", axis_devices=N, allow_stale_tables=True)
-y = np.asarray(jax.jit(shard_map(lambda a: be.all_gather(a[0], "x"),
+y = np.asarray(jax.jit(jax.shard_map(lambda a: be.all_gather(a[0], "x"),
       mesh=mesh, in_specs=P("x", None, None), out_specs=P(None, None, None),
       check_vma=False))(x))
 assert np.allclose(y, ref)
-z = np.asarray(jax.jit(shard_map(lambda a: be.reduce_scatter(a[0], "x")[None],
+z = np.asarray(jax.jit(jax.shard_map(lambda a: be.reduce_scatter(a[0], "x")[None],
       mesh=mesh, in_specs=P("x", None, None, None),
-      out_specs=P("x", None, None, None), check_vma=False))(xr))
+      out_specs=P("x", None, None), check_vma=False))(xr))
 assert np.allclose(z, expect_rs, atol=1e-4)
-w = np.asarray(jax.jit(shard_map(lambda a: be.all_reduce(a[0], "x"),
+w = np.asarray(jax.jit(jax.shard_map(lambda a: be.all_reduce(a[0], "x"),
       mesh=mesh, in_specs=P("x", None, None, None),
       out_specs=P(None, None, None), check_vma=False))(xr))
 assert np.allclose(w, expect_rs, atol=1e-4)
@@ -82,6 +82,30 @@ print("LATTE_OK")
 @pytest.mark.slow
 def test_latte_collectives_match_reference(subproc):
     assert "LATTE_OK" in subproc(LATTE_TEST, n_devices=8)
+
+
+CHECK_TEST = r"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.launch import collectives_check as cc
+from repro.launch.mesh import make_mesh
+
+mesh = make_mesh((4,), ("x",))
+n = cc.run(mesh, "x", cases=((4096, jnp.float32), (4096, jnp.bfloat16)),
+           log=lambda *_: None)
+assert n == 2 * (7 + 4 + 2 + 2), n
+# the comparison is not vacuous: one flipped element shows on its device only
+a = jnp.zeros((4, 4, 2, 128), jnp.float32)
+bad = np.asarray(cc._mismatch_fn(mesh, "x", P("x", None, None, None))(a, a.at[2, 0, 0, 0].set(1.0)))
+assert bad.tolist() == [0, 0, 1, 0], bad
+print("CHECK_OK")
+"""
+
+
+def test_collectives_check_on_four_devices(subproc):
+    """The four-chip phase of chip_smoke.py, at 4 KB per device on four
+    emulated CPU devices (Pallas kernels in interpret mode)."""
+    assert "CHECK_OK" in subproc(CHECK_TEST, n_devices=4)
 
 
 def test_dispatch_tables_structure():
@@ -106,7 +130,10 @@ class _AnyImpl(dict):
     """Stands in for the _*_IMPL maps: any winner resolves to a stub so the
     dispatch path runs outside shard_map."""
 
-    def get(self, key, default=None):
+    def __contains__(self, key):
+        return True
+
+    def __getitem__(self, key):
         return lambda x, axis_name: ("dispatched", key)
 
 
@@ -148,6 +175,26 @@ def test_latte_dispatch_warns_on_stale_fingerprint(monkeypatch, tmp_path):
         assert out[0] == "dispatched"
     finally:
         backend._bundled_current.cache_clear()
+
+
+def test_latte_dispatch_raises_on_unmapped_winner(monkeypatch):
+    """A table winner with no JAX implementation must fail loudly rather
+    than run XLA's collective in its place."""
+    monkeypatch.setattr(backend, "_AG_IMPL", {})
+    with pytest.raises(ValueError, match="no JAX implementation"):
+        CommBackend("latte").all_gather(_stub_array(1 << 20), "x")
+
+
+@pytest.mark.parametrize("n_devices", [4, 8])
+def test_every_table_winner_is_mapped(n_devices):
+    """Every winner in the derived 4- and 8-device tables has an
+    implementation (test_dispatch_cache covers the bundled 16-device ones),
+    so raising on an unmapped winner changes no dispatch."""
+    be = CommBackend("latte", axis_devices=n_devices)
+    impls = (backend._AG_IMPL, backend._AA_IMPL, backend._RS_IMPL,
+             backend._AR_IMPL)
+    for table, impl in zip(tpu_dispatch_tables(n_devices), impls):
+        assert {be._strip(e.variant) for e in table} <= set(impl)
 
 
 def test_reference_backend_never_consults_tables():

@@ -48,8 +48,8 @@ class TestDecodeAttention:
         ks = jax.random.split(jax.random.PRNGKey(B * 31 + mb), 4)
         npool = mb * B + 2
         q = jax.random.normal(ks[0], (B, KV, G, hd)).astype(dtype)
-        kp = jax.random.normal(ks[1], (npool, bt, KV, hd)).astype(dtype)
-        vp = jax.random.normal(ks[2], (npool, bt, KV, hd)).astype(dtype)
+        kp = jax.random.normal(ks[1], (npool, KV, bt, hd)).astype(dtype)
+        vp = jax.random.normal(ks[2], (npool, KV, bt, hd)).astype(dtype)
         tables = jax.random.randint(ks[3], (B, mb), 0, npool)
         lengths = jnp.asarray(np.random.default_rng(0).integers(1, mb * bt, B),
                               jnp.int32)
@@ -61,8 +61,8 @@ class TestDecodeAttention:
     def test_softcap(self):
         ks = jax.random.split(jax.random.PRNGKey(9), 4)
         q = jax.random.normal(ks[0], (2, 2, 4, 128))
-        kp = jax.random.normal(ks[1], (8, 16, 2, 128))
-        vp = jax.random.normal(ks[2], (8, 16, 2, 128))
+        kp = jax.random.normal(ks[1], (8, 2, 16, 128))
+        vp = jax.random.normal(ks[2], (8, 2, 16, 128))
         tables = jax.random.randint(ks[3], (2, 4), 0, 8)
         lengths = jnp.array([60, 33], jnp.int32)
         out = decode_attention(q, kp, vp, tables, lengths, softcap=30.0, interpret=True)
@@ -73,8 +73,8 @@ class TestDecodeAttention:
         """Changing K/V beyond `length` must not change the output."""
         ks = jax.random.split(jax.random.PRNGKey(3), 4)
         q = jax.random.normal(ks[0], (1, 1, 4, 128))
-        kp = jax.random.normal(ks[1], (4, 16, 1, 128))
-        vp = jax.random.normal(ks[2], (4, 16, 1, 128))
+        kp = jax.random.normal(ks[1], (4, 1, 16, 128))
+        vp = jax.random.normal(ks[2], (4, 1, 16, 128))
         tables = jnp.array([[0, 1, 2, 3]], jnp.int32)
         lengths = jnp.array([20], jnp.int32)
         out1 = decode_attention(q, kp, vp, tables, lengths, interpret=True)
@@ -86,7 +86,7 @@ class TestDecodeAttention:
 
 DIST_TEST = r"""
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.kernels.ring_all_gather.ops import ring_all_gather
 from repro.kernels.ring_all_gather.ref import all_gather_ref
 from repro.kernels.ring_all_to_all.ops import pallas_all_to_all
@@ -107,20 +107,6 @@ print("DIST_OK")
 """
 
 
-def _has_pallas_tpu_interpret() -> bool:
-    """The remote-DMA kernels use TPU semaphores + remote async copies, which
-    only run off-TPU under the pallas TPU interpret mode (pltpu.InterpretParams,
-    jax >= 0.5).  The generic interpreter of older jax has no lowering for
-    ``get_barrier_semaphore`` and friends on CPU."""
-    from jax.experimental.pallas import tpu as pltpu
-    return hasattr(pltpu, "InterpretParams")
-
-
-@pytest.mark.skipif(
-    not _has_pallas_tpu_interpret(),
-    reason="remote-DMA Pallas kernels need real TPUs or pallas TPU interpret "
-           "mode (jax >= 0.5); this jax's generic interpreter lacks TPU "
-           "semaphore primitives on CPU")
 def test_remote_dma_collective_kernels(subproc):
     out = subproc(DIST_TEST, n_devices=8)
     assert "DIST_OK" in out

@@ -4,7 +4,7 @@ import pytest
 LATTE_MOE_TEST = r"""
 import dataclasses
 import jax, jax.numpy as jnp, numpy as np
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.core.latte_moe import make_latte_moe
 from repro.models import moe as moe_mod

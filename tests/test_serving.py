@@ -71,6 +71,18 @@ def test_generation_identical_across_backends(engine):
         np.testing.assert_array_equal(hit.tokens, miss.tokens)
 
 
+def test_serve_entry_point_checks_pass_on_reduced_config():
+    """The served path of chip_smoke.py (miss, then hits through every
+    fetch backend, with its bitwise and logit checks) on the toy preset."""
+    from repro.launch import serve
+
+    lines = []
+    serve.run(get_config("qwen2-0.5b").reduced(), batch=2, ctx=48, new=3,
+              log=lines.append)
+    assert sum(line.startswith("[hit/") for line in lines) == len(serve.FETCH_BACKENDS)
+    assert sum(line.startswith("[check]") for line in lines) == 3
+
+
 def test_requires_decoder_family():
     cfg = get_config("rwkv6-1.6b").reduced()
     model = build_model(cfg)
