@@ -7,7 +7,10 @@ store), then hits through every fetch backend.  It checks that each backend
 fetches exactly the bytes that were saved, that all hit backends decode the
 same tokens, and that the hit path's first-token logits agree with the miss
 path's.  Times are host-clock wall times on the device JAX runs on; a
-warm-up pass on other keys compiles every program first.
+warm-up pass on other keys compiles every program first.  Each batch also
+reports what ``repro.serve.counters`` counted while it ran: bytes moved
+each way by the fetch or the prefill K/V pull, bytes uploaded to rebuild
+the cache, and host syncs in decode.
 
     python -m repro.launch.serve                      # qwen2-0.5b, 8 x 1024 tokens
     JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced --batch 2 --ctx 64
@@ -27,6 +30,7 @@ import jax
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ArchConfig
 from repro.models import build_model
+from repro.serve.counters import counters
 from repro.serve.engine import ServeEngine
 from .compile_cache import compile_stats, enable_compile_cache
 
@@ -64,12 +68,17 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
     log(f"[serve] {batch} requests x {ctx} prompt tokens, {new} new tokens each, "
         f"fetch backends {', '.join(FETCH_BACKENDS)}")
 
+    def counted(prompts, keys, backend=None):
+        """(generation, counter deltas) of one batch."""
+        c0 = counters()
+        res = eng.generate(prompts, keys, new, fetch_backend=backend)
+        return res, {k: n - c0.get(k, 0) for k, n in counters().items()}
+
     def one_pass(tag: str):
         prompts = rng.integers(0, cfg.vocab, (batch, ctx)).astype(np.int32)
         keys = [f"{tag}-{i}" for i in range(batch)]
-        miss = eng.generate(prompts, keys, new)
-        hits = {b: eng.generate(prompts, keys, new, fetch_backend=b)
-                for b in FETCH_BACKENDS}
+        miss = counted(prompts, keys)
+        hits = {b: counted(prompts, keys, b) for b in FETCH_BACKENDS}
         return keys, miss, hits
 
     t0 = time.perf_counter()
@@ -80,17 +89,25 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
         f"compilation so far: {cs['compiles']} programs, {cs['compile_s']:.3f} s, "
         f"{cs['cache_hits']} persistent-cache hits")
 
-    keys, miss, hits = one_pass("req")
+    keys, (miss, cn), counted_hits = one_pass("req")
     assert not miss.request_stats[0].cache_hit
     st = miss.request_stats[0]
     log(f"[miss/prefill ] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
-        f"{miss.tokens_per_s_wall:.1f} tok/s ({where})")
-    for b, res in hits.items():
+        f"{miss.tokens_per_s_wall:.1f} tok/s ({where}); K/V pulled to the host "
+        f"{cn['kv.pull.to_host_bytes']} B, cache upload "
+        f"{cn['cache.build.to_device_bytes']} B, "
+        f"{cn['decode.host_syncs']} decode syncs")
+    hits = {}
+    for b, (res, cn) in counted_hits.items():
         st = res.request_stats[0]
         assert st.cache_hit, b
+        hits[b] = res
         log(f"[hit/{b:10s}] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
             f"{res.tokens_per_s_wall:.1f} tok/s ({where}); {st.n_transfers} "
-            f"transfers, modeled MI300X fetch {st.fetch_modeled_s * 1e6:.1f} us")
+            f"transfers, fetch {cn['kv.fetch.to_device_bytes']} B to the device "
+            f"and {cn['kv.fetch.to_host_bytes']} B back, cache upload "
+            f"{cn['cache.build.to_device_bytes']} B, "
+            f"{cn['decode.host_syncs']} decode syncs")
 
     for b in FETCH_BACKENDS:
         for key in keys:

@@ -1,0 +1,27 @@
+"""Running totals of what the served path moved, for this process.
+
+Plain integers, added once per call (never per token) at the layer
+boundaries of ``engine.py`` and ``host_store.py``:
+
+* ``kv.fetch.to_device_bytes`` / ``kv.fetch.to_host_bytes``: bytes of every
+  copy inside ``HostKVStore.fetch``, each way;
+* ``kv.fetch.tokens``: context tokens fetched;
+* ``kv.pull.to_host_bytes``: K/V pulled to the host after prefill;
+* ``cache.build.to_device_bytes``: host arrays uploaded to rebuild a cache;
+* ``decode.host_syncs``: device-to-host syncs in the decode loop.
+
+Take a snapshot before and after the work of interest and subtract:
+the totals are shared by every engine in the process.
+"""
+from __future__ import annotations
+
+_counts: dict[str, int] = {}
+
+
+def add(name: str, n: int) -> None:
+    _counts[name] = _counts.get(name, 0) + int(n)
+
+
+def counters() -> dict[str, int]:
+    """A snapshot of every total so far."""
+    return dict(_counts)

@@ -15,8 +15,16 @@ Serving flow (mirrors the paper's vLLM + KV-offload setup, §5.3):
 TTFT therefore = fetch(+rebuild) time on hits vs prefill time on misses —
 exactly the quantity Figures 16/17 study.  Wall times are host-clock spans
 that end in a device-to-host copy of the result (so the device has
-finished); they describe whatever backend ran them.  ``fetch_modeled_s`` is
-the MI300X DMA model's prediction, not a measurement.
+finished); they describe whatever backend ran them.
+
+Each layer boundary opens a ``jax.profiler.TraceAnnotation``, which costs
+about a microsecond and records only while the profiler runs, on the clock
+of the device trace: ``serve.generate`` > ``serve.first_token`` (the TTFT
+interval) > ``serve.kv.fetch`` (``host_store.py``), ``serve.kv.unpack``,
+``serve.cache.build``, ``serve.step.first`` on a hit, ``serve.prefill`` >
+``serve.kv.pull`` on a miss; then ``serve.kv.save`` and ``serve.cache.build``
+(miss), ``serve.first_logits`` and ``serve.decode``.  No span opens per
+decoded token.  Byte and sync totals go to ``repro.serve.counters``.
 
 Concurrent-traffic serving (DESIGN.md §12): :class:`ServingSimulator` is
 the *modeled* counterpart for load studies — a continuous-batching loop
@@ -40,6 +48,7 @@ import jax.numpy as jnp
 from repro.core.backend import CommBackend
 from repro.models import attention as attn_mod
 from repro.models.transformer import Model
+from . import counters
 from .host_store import HostKVStore
 from .kvcache import BLOCK_TOKENS, blocks_to_kv, kv_to_blocks
 
@@ -49,7 +58,6 @@ class RequestStats:
     key: str
     cache_hit: bool
     ttft_wall_s: float          # the batch's wall time to its first tokens
-    fetch_modeled_s: float      # MI300X model, not measured; 0 on miss
     n_transfers: int
     prompt_tokens: int
 
@@ -76,15 +84,22 @@ class ServeEngine:
         self.store = host_store or HostKVStore(block_tokens)
         self.comm = comm or CommBackend("latte")
         self.block_tokens = block_tokens
-        self._prefill_jit = jax.jit(
-            lambda p, b: model.forward(p, b, want_cache=True, remat=False))
+
+        def prefill(p, b):
+            return model.forward(p, b, want_cache=True, remat=False)
+
+        self._prefill_jit = jax.jit(prefill)
         self._decode_jit = jax.jit(model.decode_step)
 
     # ----------------------------------------------------------- helpers ----
     def _prefill(self, prompts: jax.Array):
-        logits, _, kvs = self._prefill_jit(self.params, {"tokens": prompts})
-        (k, v), = kvs      # per_unit == 1
-        return logits, np.asarray(k), np.asarray(v)   # [L, B, S, KV, hd]
+        with jax.profiler.TraceAnnotation("serve.prefill"):
+            logits, _, kvs = self._prefill_jit(self.params, {"tokens": prompts})
+            (k, v), = kvs      # per_unit == 1
+            with jax.profiler.TraceAnnotation("serve.kv.pull"):
+                k, v = np.asarray(k), np.asarray(v)   # [L, B, S, KV, hd]
+        counters.add("kv.pull.to_host_bytes", k.nbytes + v.nbytes)
+        return logits, k, v
 
     def _build_cache(self, k: np.ndarray, v: np.ndarray, capacity: int):
         """k/v [L, B, S, KV, hd] -> stacked decode cache at ``capacity``."""
@@ -94,8 +109,10 @@ class ServeEngine:
         def one_layer(kl, vl):
             return attn_mod.prefill_cache(cfg, jnp.asarray(kl), jnp.asarray(vl), capacity)
 
-        layers = [one_layer(k[i], v[i]) for i in range(L)]
-        stacked = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+        with jax.profiler.TraceAnnotation("serve.cache.build"):
+            layers = [one_layer(k[i], v[i]) for i in range(L)]
+            stacked = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+        counters.add("cache.build.to_device_bytes", k.nbytes + v.nbytes)
         return (stacked,)   # per_unit tuple
 
     def _planned_backend(self, keys: Sequence[str]) -> str:
@@ -117,57 +134,65 @@ class ServeEngine:
         capacity = capacity or S + 64
         all_hit = all(k in self.store for k in keys)
         t0 = time.perf_counter()
-        stats = []
+        with jax.profiler.TraceAnnotation("serve.first_token", batch=B, hit=all_hit):
+            if all_hit:
+                if fetch_backend is None:
+                    fetch_backend = self._planned_backend(keys)
+                ks, vs, n_tr = [], [], 0
+                for key in keys:
+                    res = self.store.fetch(key, fetch_backend)
+                    with jax.profiler.TraceAnnotation("serve.kv.unpack"):
+                        kk, vv = blocks_to_kv(res.k_blocks, res.v_blocks,
+                                              self.store.tokens_for(key))
+                    ks.append(kk)
+                    vs.append(vv)
+                    n_tr += res.n_transfers
+                with jax.profiler.TraceAnnotation("serve.kv.unpack"):
+                    k = np.concatenate(ks, axis=1)   # [L, B, S, KV, hd]
+                    v = np.concatenate(vs, axis=1)
+                cache = self._build_cache(k, v, capacity)
+                with jax.profiler.TraceAnnotation("serve.step.first"):
+                    logits, cache = self._decode_jit(
+                        self.params,
+                        {"tokens": jnp.asarray(prompts[:, -1:]), "pos": jnp.int32(S - 1)},
+                        cache)
+                    first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            else:
+                logits, k, v = self._prefill(jnp.asarray(prompts))
+                first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+            wall = time.perf_counter() - t0
         if all_hit:
-            if fetch_backend is None:
-                fetch_backend = self._planned_backend(keys)
-            ks, vs, modeled_total, n_tr = [], [], 0.0, 0
-            for key in keys:
-                res = self.store.fetch(key, fetch_backend)
-                kk, vv = blocks_to_kv(res.k_blocks, res.v_blocks, self.store.tokens_for(key))
-                ks.append(kk)
-                vs.append(vv)
-                modeled_total += res.modeled_seconds
-                n_tr += res.n_transfers
-            k = np.concatenate(ks, axis=1)   # [L, B, S, KV, hd]
-            v = np.concatenate(vs, axis=1)
-            cache = self._build_cache(k, v, capacity)
-            logits, cache = self._decode_jit(
-                self.params,
-                {"tokens": jnp.asarray(prompts[:, -1:]), "pos": jnp.int32(S - 1)},
-                cache)
-            first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-            wall = time.perf_counter() - t0
-            for key in keys:
-                stats.append(RequestStats(key, True, wall, modeled_total / B,
-                                          n_tr, S))
+            stats = [RequestStats(key, True, wall, n_tr, S) for key in keys]
         else:
-            logits, k, v = self._prefill(jnp.asarray(prompts))
-            first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-            wall = time.perf_counter() - t0
+            stats = []
             for b, key in enumerate(keys):
-                kb, vb = kv_to_blocks(k[:, b:b + 1], v[:, b:b + 1], self.block_tokens)
-                self.store.save(key, kb, vb, S)
-                stats.append(RequestStats(key, False, wall, 0.0, 0, S))
+                with jax.profiler.TraceAnnotation("serve.kv.save"):
+                    kb, vb = kv_to_blocks(k[:, b:b + 1], v[:, b:b + 1], self.block_tokens)
+                    self.store.save(key, kb, vb, S)
+                stats.append(RequestStats(key, False, wall, 0, S))
             cache = self._build_cache(k, v, capacity)
-        first_logits = np.asarray(logits[:, -1], np.float32)
+        with jax.profiler.TraceAnnotation("serve.first_logits"):
+            first_logits = np.asarray(logits[:, -1], np.float32)
         return first, first_logits, cache, stats
 
     def generate(self, prompts: np.ndarray, keys: Sequence[str], n_new: int,
                  *, fetch_backend: str | None = None) -> GenerationResult:
         B, S = prompts.shape
         capacity = S + n_new + 1
-        first, first_logits, cache, stats = self.first_token(
-            prompts, keys, fetch_backend=fetch_backend, capacity=capacity)
-        toks = [first]
-        cur = jnp.asarray(first)[:, None]
-        t0 = time.perf_counter()
-        for i in range(n_new - 1):
-            logits, cache = self._decode_jit(
-                self.params, {"tokens": cur, "pos": jnp.int32(S + i)}, cache)
-            cur = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-            toks.append(np.asarray(cur)[:, 0])
-        dt = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("serve.generate"):
+            first, first_logits, cache, stats = self.first_token(
+                prompts, keys, fetch_backend=fetch_backend, capacity=capacity)
+            toks = [first]
+            cur = jnp.asarray(first)[:, None]
+            with jax.profiler.TraceAnnotation("serve.decode"):
+                t0 = time.perf_counter()
+                for i in range(n_new - 1):
+                    logits, cache = self._decode_jit(
+                        self.params, {"tokens": cur, "pos": jnp.int32(S + i)}, cache)
+                    cur = jnp.argmax(logits[:, -1], axis=-1)[:, None]
+                    toks.append(np.asarray(cur)[:, 0])
+                dt = time.perf_counter() - t0
+        counters.add("decode.host_syncs", n_new - 1)
         tokens = np.stack(toks, axis=1)
         return GenerationResult(tokens, first_logits, stats, dt,
                                 B * (n_new - 1) / max(dt, 1e-9))
