@@ -9,8 +9,8 @@ same tokens, and that the hit path's first-token logits agree with the miss
 path's.  Times are host-clock wall times on the device JAX runs on; a
 warm-up pass on other keys compiles every program first.  Each batch also
 reports what ``repro.serve.counters`` counted while it ran: bytes moved
-each way by the fetch or the prefill K/V pull, bytes uploaded to rebuild
-the cache, and host syncs in decode.
+each way by the fetch or the prefill K/V pull, bytes uploaded to build a
+miss's cache, hit caches laid out on the device, and host syncs in decode.
 
     python -m repro.launch.serve                      # qwen2-0.5b, 8 x 1024 tokens
     JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced --batch 2 --ctx 64
@@ -32,6 +32,7 @@ from repro.configs.base import ArchConfig
 from repro.models import build_model
 from repro.serve.counters import counters
 from repro.serve.engine import ServeEngine
+from repro.serve.kvcache import layer_major
 from .compile_cache import compile_stats, enable_compile_cache
 
 FETCH_BACKENDS = ("pcpy", "b2b", "opt_b2b", "kernel")
@@ -105,15 +106,17 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
         log(f"[hit/{b:10s}] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
             f"{res.tokens_per_s_wall:.1f} tok/s ({where}); {st.n_transfers} "
             f"transfers, fetch {cn['kv.fetch.to_device_bytes']} B to the device "
-            f"and {cn['kv.fetch.to_host_bytes']} B back, cache upload "
-            f"{cn['cache.build.to_device_bytes']} B, "
+            f"and 0 B back, cache upload "
+            f"{cn.get('cache.build.to_device_bytes', 0)} B, "
+            f"{cn['cache.rebuild.batches']} cache rebuilt on the device, "
             f"{cn['decode.host_syncs']} decode syncs")
 
     for b in FETCH_BACKENDS:
         for key in keys:
             kb, vb = eng.store.saved(key)
             got = eng.store.fetch(key, b)
-            assert _bit_equal(got.k_blocks, kb) and _bit_equal(got.v_blocks, vb), \
+            assert (_bit_equal(np.asarray(got.k), layer_major(kb))
+                    and _bit_equal(np.asarray(got.v), layer_major(vb))), \
                 f"{b} fetched K/V that differ from what {key} saved"
     log(f"[check] fetched K/V bit-identical to the saved K/V: every backend, "
         f"{len(keys)} requests, {kb.nbytes + vb.nbytes} bytes each")

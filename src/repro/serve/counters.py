@@ -3,11 +3,14 @@
 Plain integers, added once per call (never per token) at the layer
 boundaries of ``engine.py`` and ``host_store.py``:
 
-* ``kv.fetch.to_device_bytes`` / ``kv.fetch.to_host_bytes``: bytes of every
-  copy inside ``HostKVStore.fetch``, each way;
+* ``kv.fetch.to_device_bytes``: bytes of every upload inside
+  ``HostKVStore.fetch``, which copies nothing back;
 * ``kv.fetch.tokens``: context tokens fetched;
 * ``kv.pull.to_host_bytes``: K/V pulled to the host after prefill;
-* ``cache.build.to_device_bytes``: host arrays uploaded to rebuild a cache;
+* ``cache.build.to_device_bytes``: host arrays uploaded to build a miss's
+  cache;
+* ``cache.rebuild.batches``: hit batches whose cache the device laid out
+  from the fetched K/V;
 * ``decode.host_syncs``: device-to-host syncs in the decode loop.
 
 Take a snapshot before and after the work of interest and subtract:

@@ -4,12 +4,12 @@ Serving flow (mirrors the paper's vLLM + KV-offload setup, §5.3):
 
 1. A request arrives with a context key.  On a HOST CACHE MISS the engine
    runs prefill on device, emits the first token, and SAVES the paged KV to
-   the host store.  On a HIT it FETCHES the KV blocks back, rebuilds the
-   device cache, and emits the first token with a single decode step — no
-   prefill compute.  The fetch backend defaults to the CommBackend's
-   ``kv_fetch_plan`` (latte: the optimized ``opt_b2b`` command stream,
-   DESIGN.md §7/§8; reference: per-block ``pcpy``); an explicit
-   ``fetch_backend`` string overrides the plan.
+   the host store.  On a HIT it FETCHES the KV blocks to the device, where
+   one program lays out the decode cache, and emits the first token with a
+   single decode step — no prefill compute.  The fetch backend defaults to
+   the CommBackend's ``kv_fetch_plan`` (latte: the optimized ``opt_b2b``
+   command stream, DESIGN.md §7/§8; reference: per-block ``pcpy``); an
+   explicit ``fetch_backend`` string overrides the plan.
 2. Decode proceeds in batched steps over all active sequences.
 
 TTFT therefore = fetch(+rebuild) time on hits vs prefill time on misses —
@@ -20,11 +20,12 @@ finished); they describe whatever backend ran them.
 Each layer boundary opens a ``jax.profiler.TraceAnnotation``, which costs
 about a microsecond and records only while the profiler runs, on the clock
 of the device trace: ``serve.generate`` > ``serve.first_token`` (the TTFT
-interval) > ``serve.kv.fetch`` (``host_store.py``), ``serve.kv.unpack``,
-``serve.cache.build``, ``serve.step.first`` on a hit, ``serve.prefill`` >
-``serve.kv.pull`` on a miss; then ``serve.kv.save`` and ``serve.cache.build``
-(miss), ``serve.first_logits`` and ``serve.decode``.  No span opens per
-decoded token.  Byte and sync totals go to ``repro.serve.counters``.
+interval) > ``serve.kv.fetch`` (``host_store.py``), ``serve.cache.build``
+(the dispatch of the rebuild program), ``serve.step.first`` on a hit,
+``serve.prefill`` > ``serve.kv.pull`` on a miss; then ``serve.kv.save`` and
+``serve.cache.build`` (miss), ``serve.first_logits`` and ``serve.decode``.
+No span opens per decoded token.  Byte, batch and sync totals go to
+``repro.serve.counters``.
 
 Concurrent-traffic serving (DESIGN.md §12): :class:`ServingSimulator` is
 the *modeled* counterpart for load studies — a continuous-batching loop
@@ -49,8 +50,8 @@ from repro.core.backend import CommBackend
 from repro.models import attention as attn_mod
 from repro.models.transformer import Model
 from . import counters
-from .host_store import HostKVStore
-from .kvcache import BLOCK_TOKENS, blocks_to_kv, kv_to_blocks
+from .host_store import FetchResult, HostKVStore
+from .kvcache import BLOCK_TOKENS, kv_to_blocks
 
 
 @dataclasses.dataclass
@@ -88,8 +89,16 @@ class ServeEngine:
         def prefill(p, b):
             return model.forward(p, b, want_cache=True, remat=False)
 
+        def rebuild(ks, vs, n_tokens, capacity):
+            # fetched [L, S', KV, hd] a context -> the cache _build_cache makes
+            k = jnp.stack(ks, axis=1)[:, :, :n_tokens]     # [L, B, S, KV, hd]
+            v = jnp.stack(vs, axis=1)[:, :, :n_tokens]
+            return jax.vmap(lambda kl, vl: attn_mod.prefill_cache(
+                model.cfg, kl, vl, capacity))(k, v)
+
         self._prefill_jit = jax.jit(prefill)
         self._decode_jit = jax.jit(model.decode_step)
+        self._rebuild_jit = jax.jit(rebuild, static_argnames=("n_tokens", "capacity"))
 
     # ----------------------------------------------------------- helpers ----
     def _prefill(self, prompts: jax.Array):
@@ -115,6 +124,17 @@ class ServeEngine:
         counters.add("cache.build.to_device_bytes", k.nbytes + v.nbytes)
         return (stacked,)   # per_unit tuple
 
+    def _rebuild_cache(self, fetched: Sequence[FetchResult], n_tokens: int,
+                       capacity: int):
+        """The batch's fetched K/V, already on the device -> the stacked
+        decode cache at ``capacity``, laid out by one program on the device:
+        the same arrays ``_build_cache`` makes from the same K/V."""
+        with jax.profiler.TraceAnnotation("serve.cache.build"):
+            stacked = self._rebuild_jit([r.k for r in fetched], [r.v for r in fetched],
+                                        n_tokens=n_tokens, capacity=capacity)
+        counters.add("cache.rebuild.batches", 1)
+        return (stacked,)   # per_unit tuple
+
     def _planned_backend(self, keys: Sequence[str]) -> str:
         """Fetch backend from the CommBackend's plan for these contexts
         (latte requests the optimized command stream -> ``opt_b2b``)."""
@@ -138,19 +158,12 @@ class ServeEngine:
             if all_hit:
                 if fetch_backend is None:
                     fetch_backend = self._planned_backend(keys)
-                ks, vs, n_tr = [], [], 0
-                for key in keys:
-                    res = self.store.fetch(key, fetch_backend)
-                    with jax.profiler.TraceAnnotation("serve.kv.unpack"):
-                        kk, vv = blocks_to_kv(res.k_blocks, res.v_blocks,
-                                              self.store.tokens_for(key))
-                    ks.append(kk)
-                    vs.append(vv)
-                    n_tr += res.n_transfers
-                with jax.profiler.TraceAnnotation("serve.kv.unpack"):
-                    k = np.concatenate(ks, axis=1)   # [L, B, S, KV, hd]
-                    v = np.concatenate(vs, axis=1)
-                cache = self._build_cache(k, v, capacity)
+                n_tokens = {self.store.tokens_for(key) for key in keys}
+                if len(n_tokens) != 1:
+                    raise ValueError(f"a hit batch's contexts differ in length: {n_tokens}")
+                fetched = [self.store.fetch(key, fetch_backend) for key in keys]
+                n_tr = sum(res.n_transfers for res in fetched)
+                cache = self._rebuild_cache(fetched, n_tokens.pop(), capacity)
                 with jax.profiler.TraceAnnotation("serve.step.first"):
                     logits, cache = self._decode_jit(
                         self.params,

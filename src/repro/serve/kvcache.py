@@ -2,9 +2,15 @@
 
 Device-side pools hold KV in fixed-size blocks (16 tokens by default, the
 vLLM default the paper cites); per-sequence block tables map logical block
-index -> pool slot.  All model layers of one logical block are stored
-contiguously (the [28]-style optimization the paper's baseline assumes), so
-one host<->device transfer moves a full layer-stack block.
+index -> pool slot, and a pool block holds every model layer of its tokens.
+
+On the host, ``kv_to_blocks`` gives a context's K/V the block shape
+``[n_blocks, block_tokens, L, KV, hd]`` without copying it: the blocks are a
+strided view of the batch's pulled ``[L, B, S, KV, hd]`` array, not
+contiguous blocks, so their bytes lie layer-major: one contiguous run per
+layer, within which tokens, heads and channels lie as the pulled array has
+them (a TPU hands the batch back with tokens minor-most).  A fetch reads
+them in that order and lets the device change the layout.
 """
 from __future__ import annotations
 
@@ -71,7 +77,8 @@ def blocks_for_tokens(n_tokens: int, block_tokens: int = BLOCK_TOKENS) -> int:
 
 def kv_to_blocks(k: np.ndarray, v: np.ndarray, block_tokens: int = BLOCK_TOKENS):
     """Layer-stacked prefill KV [L, B=1, S, KV, hd] -> per-block arrays
-    [n_blocks, block_tokens, L, KV, hd] (zero-padded tail)."""
+    [n_blocks, block_tokens, L, KV, hd]: views of ``k`` and ``v`` where S is
+    a multiple of ``block_tokens``, else copies with a zero-padded tail."""
     L, B, S, KV, hd = k.shape
     assert B == 1
     nb = blocks_for_tokens(S, block_tokens)
@@ -84,10 +91,10 @@ def kv_to_blocks(k: np.ndarray, v: np.ndarray, block_tokens: int = BLOCK_TOKENS)
     return conv(k), conv(v)
 
 
-def blocks_to_kv(kb: np.ndarray, vb: np.ndarray, n_tokens: int):
-    """Inverse of kv_to_blocks -> [L, 1, S, KV, hd]."""
-    def conv(a):
-        nb, bt, L, KV, hd = a.shape
-        a = a.reshape(nb * bt, L, KV, hd)[:n_tokens]
-        return np.moveaxis(a, 1, 0)[:, None]
-    return conv(kb), conv(vb)
+def layer_major(blocks):
+    """Blocks [n_blocks, bt, L, KV, hd] -> [L, n_blocks * bt, KV, hd].  On a
+    numpy array it is a view wherever the block axes can merge, as in
+    ``kv_to_blocks``'s views; on a device array, a reshape and a transpose
+    on the device."""
+    nb, bt = blocks.shape[:2]
+    return blocks.reshape(nb * bt, *blocks.shape[2:]).swapaxes(0, 1)

@@ -30,7 +30,7 @@ from repro.core.dma.claims import (pipe_vs_final_chunk_ratio,
 from repro.core.dma.collectives import AR_AG_VARIANT, _pipe_granularity
 from repro.data.pipeline import DataConfig, synth_batch
 from repro.models.layers import apply_rotary, rope_angles
-from repro.serve.kvcache import blocks_to_kv, kv_to_blocks
+from repro.serve.kvcache import kv_to_blocks, layer_major
 from repro.train.checkpoint import restore_checkpoint, save_checkpoint
 
 KB, MB = 1024, 1024 * 1024
@@ -288,7 +288,8 @@ def test_kv_block_roundtrip(s, kv, hd, layers, bt):
     k = rng.normal(size=(layers, 1, s, kv, hd)).astype(np.float32)
     v = rng.normal(size=(layers, 1, s, kv, hd)).astype(np.float32)
     kb, vb = kv_to_blocks(k, v, bt)
-    k2, v2 = blocks_to_kv(kb, vb, s)
+    # the host reference of what a fetch and rebuild undo: blocks -> [L, 1, S, KV, hd]
+    k2, v2 = (layer_major(b)[:, :s][:, None] for b in (kb, vb))
     np.testing.assert_array_equal(k, k2)
     np.testing.assert_array_equal(v, v2)
 

@@ -8,8 +8,12 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.models import build_model
+from repro.serve.counters import counters
 from repro.serve.engine import ServeEngine
 from repro.serve.host_store import HostKVStore
+from repro.serve.kvcache import layer_major
+
+BACKENDS = ("pcpy", "b2b", "opt_b2b", "kernel")
 
 
 @pytest.fixture(scope="module")
@@ -26,10 +30,11 @@ def test_fetch_backends_bitwise_equal():
     kb = rng.normal(size=(5, 16, 2, 2, 16)).astype(np.float32)
     vb = rng.normal(size=(5, 16, 2, 2, 16)).astype(np.float32)
     store.save("k", kb, vb, 70)
-    res = {b: store.fetch("k", b) for b in ("pcpy", "b2b", "opt_b2b", "kernel")}
-    for b in ("b2b", "opt_b2b", "kernel"):
-        np.testing.assert_array_equal(res["pcpy"].k_blocks, res[b].k_blocks)
-        np.testing.assert_array_equal(res["pcpy"].v_blocks, res[b].v_blocks)
+    res = {b: store.fetch("k", b) for b in BACKENDS}
+    for b in BACKENDS:
+        assert isinstance(res[b].k, jax.Array) and isinstance(res[b].v, jax.Array)
+        np.testing.assert_array_equal(np.asarray(res[b].k), layer_major(store.saved("k")[0]))
+        np.testing.assert_array_equal(np.asarray(res[b].v), layer_major(store.saved("k")[1]))
     assert res["b2b"].n_transfers < res["pcpy"].n_transfers
     # the MI300X model of the same fetch: batching beats per-block copies,
     # and the optimized command stream only tightens the latency
@@ -41,6 +46,33 @@ def test_fetch_backends_bitwise_equal():
                             ("opt_b2b", "opt_prelaunch_b2b"))}
     assert modeled["b2b"] < modeled["pcpy"]
     assert modeled["opt_b2b"] < modeled["b2b"]
+
+
+def _batch(rng, memory_axes):
+    """K of a pulled batch [L=3, B=2, S=32, KV=2, hd=8] whose bytes lie in
+    the order ``memory_axes`` gives its axes."""
+    a = rng.normal(size=(3, 2, 32, 2, 8)).astype(np.float32)
+    return np.ascontiguousarray(a.transpose(memory_axes)).transpose(np.argsort(memory_axes))
+
+
+@pytest.mark.parametrize("memory_axes", [(0, 1, 2, 3, 4), (0, 1, 3, 4, 2), (2, 1, 0, 4, 3)],
+                         ids=["c-order", "tokens-minor", "token-major"])
+def test_fetch_reads_any_stored_layout(memory_axes):
+    """Saved views of a batch whose memory is in any order (a TPU hands a
+    pulled batch back with its tokens minor-most) come back, from every
+    backend, as the layer-major K/V that was saved."""
+    from repro.serve.kvcache import kv_to_blocks
+
+    rng = np.random.default_rng(6)
+    k, v = _batch(rng, memory_axes), _batch(rng, memory_axes)
+    store = HostKVStore()
+    kb, vb = kv_to_blocks(k[:, 1:2], v[:, 1:2])
+    assert np.shares_memory(kb, k)
+    store.save("ctx", kb, vb, 32)
+    for b in BACKENDS:
+        res = store.fetch("ctx", b)
+        np.testing.assert_array_equal(np.asarray(res.k), k[:, 1])
+        np.testing.assert_array_equal(np.asarray(res.v), v[:, 1])
 
 
 def test_engine_follows_kv_fetch_plan():
@@ -76,6 +108,84 @@ def test_generation_identical_across_backends(engine):
         hit = eng.generate(prompts, keys, 6, fetch_backend=backend)
         assert hit.request_stats[0].cache_hit
         np.testing.assert_array_equal(hit.tokens, miss.tokens)
+
+
+def _cache_arrays(cache):
+    (c,) = cache
+    return {name: c[name] for name in ("k", "v", "kpos")}
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("S,capacity", [(48, 60), (48, 32), (40, 52)],
+                         ids=["views", "views-rolling", "padded-copies"])
+def test_hit_cache_equals_miss_cache(engine, backend, S, capacity):
+    """The decode cache the device lays out from a hit's fetched K/V is, bit
+    for bit and in shape, dtype and placement, the cache the miss path
+    builds from the pulled K/V: with room to spare, in a rolling window
+    shorter than the context, and from block copies with a padded tail."""
+    eng, cfg = engine
+    prompts = np.random.default_rng(S + capacity).integers(
+        0, cfg.vocab, (2, S)).astype(np.int32)
+    keys = [f"cache-{backend}-{S}-{capacity}-{i}" for i in range(2)]
+    *_, miss_cache, _ = eng.first_token(prompts, keys, capacity=capacity)
+    hit_cache = eng._rebuild_cache([eng.store.fetch(k, backend) for k in keys], S, capacity)
+    miss, hit = _cache_arrays(miss_cache), _cache_arrays(hit_cache)
+    for name in miss:
+        assert hit[name].shape == miss[name].shape and hit[name].dtype == miss[name].dtype
+        assert hit[name].sharding == miss[name].sharding
+        np.testing.assert_array_equal(np.asarray(hit[name]), np.asarray(miss[name]))
+    assert hit["k"].shape[2] == capacity
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_hit_moves_each_saved_byte_once(engine, backend):
+    """A hit uploads each saved byte once, copies none back, and uploads no
+    host array to build the cache: the device lays it out."""
+    eng, cfg = engine
+    prompts = np.random.default_rng(4).integers(0, cfg.vocab, (2, 32)).astype(np.int32)
+    keys = [f"once-{backend}-{i}" for i in range(2)]
+    eng.first_token(prompts, keys)
+    saved = sum(a.nbytes for k in keys for a in eng.store.saved(k))
+    c0 = counters()
+    out = eng.first_token(prompts, keys, fetch_backend=backend)
+    moved = {k: n - c0.get(k, 0) for k, n in counters().items()}
+    assert out[3][0].cache_hit
+    assert moved["kv.fetch.to_device_bytes"] == saved
+    assert moved.get("kv.fetch.to_host_bytes", 0) == 0
+    assert moved.get("cache.build.to_device_bytes", 0) == 0
+    assert moved["cache.rebuild.batches"] == 1
+
+
+def test_second_hit_batch_compiles_nothing(engine):
+    """A hit's cache has the miss cache's shapes, dtypes and placement, so
+    the decode step compiled for the miss serves it; and a second hit batch
+    of the same shape compiles no program at all."""
+    eng, cfg = engine
+    rng = np.random.default_rng(5)
+    batches = [(rng.integers(0, cfg.vocab, (2, 36)).astype(np.int32),
+                [f"compile-{j}-{i}" for i in range(2)]) for j in range(2)]
+    for prompts, keys in batches:
+        eng.generate(prompts, keys, 3)                 # misses: prefill and decode
+    decode_programs = eng._decode_jit._cache_size()
+    eng.generate(*batches[0], 3)                       # first hit: compiles the rebuild
+    assert eng._decode_jit._cache_size() == decode_programs
+    rebuild_programs = eng._rebuild_jit._cache_size()
+
+    compiled = []
+
+    def listen(event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        res = eng.generate(*batches[1], 3)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert res.request_stats[0].cache_hit
+    assert compiled == []
+    assert eng._rebuild_jit._cache_size() == rebuild_programs
+    assert eng._decode_jit._cache_size() == decode_programs
 
 
 def test_serve_entry_point_checks_pass_on_reduced_config():
