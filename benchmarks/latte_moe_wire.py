@@ -40,7 +40,7 @@ def run(verbose: bool = True):
         return sum(w.values()), w
 
     def gspmd_loss(p, x):
-        out, aux = moe_mod.apply_moe(cfg, p, x)
+        out, aux, _ = moe_mod.apply_moe(cfg, p, x)
         return jnp.sum(out.astype(jnp.float32)) + aux
 
     p_sh = {"router": NamedSharding(mesh, P(None, None)),

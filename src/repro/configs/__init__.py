@@ -16,6 +16,7 @@ _MODULES = {
     "mixtral-8x7b": "mixtral_8x7b",
     "whisper-tiny": "whisper_tiny",
     "gemma2-27b": "gemma2_27b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 ARCH_IDS = tuple(_MODULES)

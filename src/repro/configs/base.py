@@ -14,11 +14,37 @@ from typing import Literal
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int
+    n_experts: int                        # the router's width
     top_k: int
     d_ff_expert: int
-    capacity_factor: float = 1.25
+    # None: dropless (every route computed); a factor: routes past
+    # ceil(tokens * top_k / n_experts * factor) per expert are dropped
+    capacity_factor: float | None = 1.25
     router_aux_weight: float = 0.01
+    # "softmax": top-k of softmax(logits); "sigmoid": DeepSeek-V3's noaux_tc,
+    # top-k of sigmoid(logits) + a per-expert selection bias, weighted by
+    # the sigmoid scores alone
+    scoring: Literal["softmax", "sigmoid"] = "softmax"
+    routed_scaling: float = 1.0           # factor on the normalised top-k weights
+    n_shared_experts: int = 0             # one shared MLP of n * d_ff_expert
+    # (first, count): the experts this device holds under expert
+    # parallelism; routes to the others add nothing here.  None: all.
+    held: tuple[int, int] | None = None
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held or (0, self.n_experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query LoRA:
+    the cache holds one RMS-normed latent of ``kv_lora_rank`` and one
+    rotated key part of ``qk_rope_head_dim`` per token and layer."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,8 +93,11 @@ class ArchConfig:
     final_softcap: float | None = None
     sliding_window: int | None = None      # SWA window (mixtral, gemma2 local)
     layer_pattern: tuple[str, ...] | None = None  # e.g. ("local","global") cycled
+    norm_eps: float = 1e-6
+    first_dense_layers: int = 0           # leading layers with a dense MLP before the MoE ones
 
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
     ssm: SSMConfig | None = None
     hybrid: HybridConfig | None = None
     encdec: EncDecConfig | None = None
@@ -110,11 +139,21 @@ class ArchConfig:
             per = 4 * D * D + D * D + 2 * D * F  # r,k,v,g,o + channel-mix
             return total + L * per
         per = 0
-        if self.n_heads:
+        if self.mla:
+            a = self.mla
+            per += (D * self.n_heads * (a.qk_nope_head_dim + a.qk_rope_head_dim)
+                    + D * (a.kv_lora_rank + a.qk_rope_head_dim)
+                    + a.kv_lora_rank * self.n_heads * (a.qk_nope_head_dim + a.v_head_dim)
+                    + self.n_heads * a.v_head_dim * D)
+        elif self.n_heads:
             per += D * self.n_heads * hd + 2 * D * self.n_kv_heads * hd + self.n_heads * hd * D
         if self.moe:
+            # leading dense layers: attention and a dense MLP each
+            total += self.first_dense_layers * (per + 3 * D * F)
+            L -= self.first_dense_layers
             per_expert = 3 * D * self.moe.d_ff_expert
-            per += D * self.moe.n_experts + self.moe.n_experts * per_expert
+            n_held = self.moe.held_range[1]
+            per += D * self.moe.n_experts + (n_held + self.moe.n_shared_experts) * per_expert
         else:
             per += 3 * D * F if self.act == "silu" else 2 * D * F
         if self.hybrid and self.ssm:
@@ -135,8 +174,8 @@ class ArchConfig:
         """Active parameters per token (MoE uses top_k experts only)."""
         if not self.moe:
             return self.n_params
-        D, L = self.d_model, self.n_layers
-        inactive = L * (self.moe.n_experts - self.moe.top_k) * 3 * D * self.moe.d_ff_expert
+        D, L = self.d_model, self.n_layers - self.first_dense_layers
+        inactive = L * (self.moe.held_range[1] - self.moe.top_k) * 3 * D * self.moe.d_ff_expert
         return self.n_params - inactive
 
     def reduced(self) -> "ArchConfig":
@@ -161,7 +200,13 @@ class ArchConfig:
         if self.moe:
             changes["moe"] = dataclasses.replace(
                 self.moe, n_experts=min(self.moe.n_experts, 4),
-                top_k=min(self.moe.top_k, 2), d_ff_expert=min(self.moe.d_ff_expert, 256))
+                top_k=min(self.moe.top_k, 2), d_ff_expert=min(self.moe.d_ff_expert, 256),
+                n_shared_experts=min(self.moe.n_shared_experts, 1), held=None)
+        if self.mla:
+            changes["mla"] = MLAConfig(kv_lora_rank=min(self.mla.kv_lora_rank, 64),
+                                       qk_nope_head_dim=min(self.mla.qk_nope_head_dim, 32),
+                                       qk_rope_head_dim=min(self.mla.qk_rope_head_dim, 16),
+                                       v_head_dim=min(self.mla.v_head_dim, 32))
         if self.ssm:
             changes["ssm"] = dataclasses.replace(
                 self.ssm, state_size=min(self.ssm.state_size, 16),

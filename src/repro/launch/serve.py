@@ -94,7 +94,7 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
     assert not miss.request_stats[0].cache_hit
     st = miss.request_stats[0]
     log(f"[miss/prefill ] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
-        f"{miss.tokens_per_s_wall:.1f} tok/s ({where}); K/V pulled to the host "
+        f"{miss.tokens_per_s_wall:.1f} tok/s ({where}); cache state pulled to the host "
         f"{cn['kv.pull.to_host_bytes']} B, cache upload "
         f"{cn['cache.build.to_device_bytes']} B, "
         f"{cn['decode.host_syncs']} decode syncs")
@@ -113,13 +113,14 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
 
     for b in FETCH_BACKENDS:
         for key in keys:
-            kb, vb = eng.store.saved(key)
+            saved = eng.store.saved(key)
             got = eng.store.fetch(key, b)
-            assert (_bit_equal(np.asarray(got.k), layer_major(kb))
-                    and _bit_equal(np.asarray(got.v), layer_major(vb))), \
-                f"{b} fetched K/V that differ from what {key} saved"
-    log(f"[check] fetched K/V bit-identical to the saved K/V: every backend, "
-        f"{len(keys)} requests, {kb.nbytes + vb.nbytes} bytes each")
+            assert all(_bit_equal(np.asarray(got.state[name]), layer_major(blocks))
+                       for name, blocks in saved.items()), \
+                f"{b} fetched a cache state that differs from what {key} saved"
+    log(f"[check] fetched cache state bit-identical to the saved state "
+        f"({', '.join(saved)}): every backend, {len(keys)} requests, "
+        f"{sum(a.nbytes for a in saved.values())} bytes each")
 
     ref_tokens = hits[FETCH_BACKENDS[0]].tokens
     for b, res in hits.items():

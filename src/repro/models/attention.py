@@ -170,26 +170,25 @@ def init_attn_cache(cfg: ArchConfig, batch: int, capacity: int) -> dict:
     }
 
 
-def prefill_cache(cfg: ArchConfig, k: jax.Array, v: jax.Array, capacity: int) -> dict:
-    """Build a cache from prefill K/V [B, S, KV, hd] (S <= capacity; for a
-    sliding-window cache, capacity = window and the tail of the sequence is
-    kept)."""
-    B, S = k.shape[:2]
+def prefill_cache(cfg: ArchConfig, state: dict, capacity: int) -> dict:
+    """Build a cache from a layer's prefill state: leaves [B, S, ...] with a
+    token axis, K and V or a latent and its rotated key (S <= capacity;
+    for a sliding-window cache, capacity = window and the tail of the
+    sequence is kept)."""
+    S = next(iter(state.values())).shape[1]
     if S > capacity:          # rolling window: keep last `capacity` tokens
-        k = k[:, S - capacity:]
-        v = v[:, S - capacity:]
         kpos = jnp.arange(S - capacity, S, dtype=jnp.int32)
         # slot layout must match pos % capacity
-        slots = kpos % capacity
-        order = jnp.argsort(slots)
-        k, v, kpos = k[:, order], v[:, order], kpos[order]
+        order = jnp.argsort(kpos % capacity)
+        out = {name: a[:, S - capacity:][:, order] for name, a in state.items()}
+        kpos = kpos[order]
     else:
         pad = capacity - S
-        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        out = {name: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+               for name, a in state.items()}
         kpos = jnp.concatenate([jnp.arange(S, dtype=jnp.int32),
                                 jnp.full((pad,), -1, jnp.int32)])
-    return {"k": k, "v": v, "kpos": kpos}
+    return {**out, "kpos": kpos}
 
 
 def attention_decode(

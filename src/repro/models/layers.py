@@ -23,7 +23,8 @@ def init_norm(cfg: ArchConfig, d: int) -> dict:
     return p
 
 
-def apply_norm(cfg: ArchConfig, p: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
+def apply_norm(cfg: ArchConfig, p: dict, x: jax.Array) -> jax.Array:
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
         var = jnp.mean(xf * xf, axis=-1, keepdims=True)
@@ -85,8 +86,7 @@ def sinusoidal_embedding(seq: int, d: int) -> jax.Array:
 def init_mlp(cfg: ArchConfig, rng: jax.Array, d: int, f: int) -> dict:
     pd = _dtype(cfg.param_dtype)
     k1, k2, k3 = jax.random.split(rng, 3)
-    scale_in = 1.0 / np.sqrt(d)
-    scale_out = 1.0 / np.sqrt(f)
+    scale_in, scale_out = d ** -0.5, f ** -0.5   # Python floats keep ``pd``
     if cfg.act == "silu":
         return {
             "wg": jax.random.normal(k1, (d, f), pd) * scale_in,

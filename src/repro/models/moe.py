@@ -1,17 +1,32 @@
-"""Mixture-of-Experts with sort-based capacity dispatch.
+"""Mixture-of-Experts: routing, then a dropless grouped dispatch or a
+sort-based capacity dispatch, then the shared experts.
 
 Design notes (DESIGN.md §6/§7):
-* Dispatch is gather/scatter-based (argsort by expert id + per-expert
-  capacity buffer), NOT a dense [T, E, C] one-hot einsum — so the compiled
-  FLOPs equal the *active* expert FLOPs, keeping the roofline honest.
-* The expert buffer [E, C, D] carries a sharding constraint that places the
+* Routing: ``softmax`` (top-k of the softmax, renormalised) or DeepSeek-V3's
+  ``sigmoid`` (noaux_tc): top-k of sigmoid scores plus a per-expert
+  selection bias, each chosen expert weighted by its score alone,
+  normalised to sum 1, times ``routed_scaling``.  The router runs in float32.
+* Expert parallelism: a device holds ``MoEConfig.held`` experts, (first,
+  count), routes over all ``n_experts`` and computes its own experts' part
+  of the result; routes to the others add nothing here (on one chip, with no
+  exchange).  Its weights are [count, ...].
+* ``capacity_factor=None``: dropless.  Routes are sorted by held expert and
+  each expert's contiguous rows go through grouped matmuls
+  (``jax.lax.ragged_dot``), so every route is computed and no buffer is
+  padded to a capacity.
+* ``capacity_factor`` set: dispatch is gather/scatter-based (argsort by
+  expert id + per-expert capacity buffer), NOT a dense [T, E, C] one-hot
+  einsum — so the compiled FLOPs equal the *active* expert FLOPs.  The
+  expert buffer [E, C, D] carries a sharding constraint that places the
   expert axis on the 'model' mesh axis when divisible (expert parallelism):
   GSPMD then materializes the token exchange as all-to-all — exactly the
   collective the paper optimizes (swap/b2b for latency-bound sizes, §4.3).
   When E < mesh width (mixtral: 8 experts on 16 chips), the expert FFN
-  hidden dim is sharded instead (tensor-parallel experts).
-* Every token keeps its top-k weights; tokens over capacity are dropped
-  (capacity_factor 1.25), as in Switch/GShard-style systems.
+  hidden dim is sharded instead (tensor-parallel experts).  Every token
+  keeps its top-k weights; routes over capacity are dropped, as in
+  Switch/GShard-style systems.
+* Shared experts: one MLP of ``n_shared_experts * d_ff_expert`` every token
+  goes through, added once.
 """
 from __future__ import annotations
 
@@ -20,20 +35,28 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from . import layers as L
 
 
 def init_moe(cfg: ArchConfig, rng: jax.Array) -> dict:
-    assert cfg.moe is not None
+    m = cfg.moe
+    assert m is not None
     pd = jnp.dtype(cfg.param_dtype)
-    D, E, F = cfg.d_model, cfg.moe.n_experts, cfg.moe.d_ff_expert
-    ks = jax.random.split(rng, 4)
-    s_in, s_out = 1.0 / np.sqrt(D), 1.0 / np.sqrt(F)
-    return {
-        "router": jax.random.normal(ks[0], (D, E), pd) * s_in,
+    D, F = cfg.d_model, m.d_ff_expert
+    E = m.held_range[1]
+    ks = jax.random.split(rng, 6)
+    s_in, s_out = D ** -0.5, F ** -0.5          # Python floats keep ``pd``
+    p = {
+        "router": jax.random.normal(ks[0], (D, m.n_experts), pd) * s_in,
         "wg": jax.random.normal(ks[1], (E, D, F), pd) * s_in,
         "wu": jax.random.normal(ks[2], (E, D, F), pd) * s_in,
         "wd": jax.random.normal(ks[3], (E, F, D), pd) * s_out,
     }
+    if m.scoring == "sigmoid":
+        p["bias"] = jnp.zeros((m.n_experts,), jnp.float32)
+    if m.n_shared_experts:
+        p["shared"] = L.init_mlp(cfg, ks[4], D, m.n_shared_experts * F)
+    return p
 
 
 def capacity_for(cfg: ArchConfig, n_tokens: int) -> int:
@@ -45,44 +68,66 @@ def capacity_for(cfg: ArchConfig, n_tokens: int) -> int:
     return max(8, int(np.ceil(cap / mult)) * mult)
 
 
-def apply_moe(
-    cfg: ArchConfig,
-    p: dict,
-    x: jax.Array,                      # [B, S, D]
-    *,
-    expert_sharding=None,              # optional fn: buffer [E,C,D/F] -> constrained
-) -> tuple[jax.Array, jax.Array]:
-    """Returns (output [B,S,D], router aux loss scalar)."""
+def route(cfg: ArchConfig, p: dict, xf: jax.Array):
+    """xf [T, D] -> (weights [T, K] f32, experts [T, K], aux loss)."""
     m = cfg.moe
-    B, S, D = x.shape
-    T = B * S
+    T = xf.shape[0]
     E, K = m.n_experts, m.top_k
-    C = capacity_for(cfg, T)
-    cd = x.dtype
-
-    xf = x.reshape(T, D)
-    logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))  # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    topk_p, topk_e = jax.lax.top_k(probs, K)                              # [T, K]
-    topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
+    logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)     # [T, E]
+    if m.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, topk_e = jax.lax.top_k(probs + p["bias"].astype(jnp.float32), K)
+        topk_p = jnp.take_along_axis(probs, topk_e, axis=-1)
+        topk_p = topk_p / (jnp.sum(topk_p, axis=-1, keepdims=True) + 1e-20)
+        probs = probs / jnp.sum(probs, axis=-1, keepdims=True)          # for the aux loss
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        topk_p, topk_e = jax.lax.top_k(probs, K)                          # [T, K]
+        topk_p = topk_p / jnp.sum(topk_p, axis=-1, keepdims=True)
+    topk_p = topk_p * m.routed_scaling
 
     # ---- load-balance auxiliary loss (Switch-style) ----
     me = jnp.mean(probs, axis=0)                                          # [E]
     assign = jnp.zeros((E,), jnp.float32).at[topk_e.reshape(-1)].add(1.0)
     ce = assign / (T * K)
     aux = E * jnp.sum(me * ce) * m.router_aux_weight
+    return topk_p, topk_e, aux
 
-    # ---- sort-based dispatch ----
-    flat_e = topk_e.reshape(-1)                                           # [T*K]
+
+def _dropless(p: dict, xf, topk_p, local, held):
+    """Every route to a held expert through grouped matmuls, rows sorted by
+    expert; the others (``local == held``) sort last and add nothing."""
+    T, K = local.shape
+    cd = xf.dtype
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+    xs = xf[order // K]                                                   # [T*K, D]
+    h = (jax.nn.silu(jax.lax.ragged_dot(xs, p["wg"].astype(cd), sizes))
+         * jax.lax.ragged_dot(xs, p["wu"].astype(cd), sizes))
+    y = jax.lax.ragged_dot(h, p["wd"].astype(cd), sizes)                  # [T*K, D]
+    y = jnp.where((flat[order] < held)[:, None], y, 0)
+    y = y[jnp.argsort(order)].reshape(T, K, -1)                           # back to [T, K, D]
+    w = jnp.where(local < held, topk_p, 0.0)
+    return jnp.einsum("tkd,tk->td", y, w.astype(cd),
+                      preferred_element_type=jnp.float32).astype(cd)
+
+
+def _capacity(cfg: ArchConfig, p: dict, xf, topk_p, local, held, expert_sharding):
+    T, K = local.shape
+    C = capacity_for(cfg, T)
+    cd = xf.dtype
+    D = xf.shape[1]
+    flat_e = local.reshape(-1)                                            # [T*K]
     order = jnp.argsort(flat_e)                                           # [T*K]
     sorted_e = flat_e[order]
-    group_start = jnp.searchsorted(sorted_e, jnp.arange(E, dtype=sorted_e.dtype))
+    group_start = jnp.searchsorted(sorted_e, jnp.arange(held + 1, dtype=sorted_e.dtype))
     pos = jnp.arange(T * K, dtype=jnp.int32) - group_start[sorted_e].astype(jnp.int32)
-    keep = pos < C
+    keep = (pos < C) & (sorted_e < held)
     pos_c = jnp.where(keep, pos, C)                                       # C == drop slot
     token_of = (order // K).astype(jnp.int32)
 
-    buf = jnp.zeros((E, C, D), cd)
+    buf = jnp.zeros((held, C, D), cd)
     buf = buf.at[sorted_e, pos_c].set(xf[token_of], mode="drop")
     if expert_sharding is not None:
         buf = expert_sharding(buf)
@@ -98,7 +143,32 @@ def apply_moe(
         y = expert_sharding(y)
 
     # ---- combine: gather back + weighted sum over k ----
-    contrib = y[sorted_e, pos_c] * keep[:, None].astype(cd)               # [T*K, D]
+    contrib = y[jnp.minimum(sorted_e, held - 1), pos_c] * keep[:, None].astype(cd)  # [T*K, D]
     weights = topk_p.reshape(-1)[order].astype(cd)
-    out = jnp.zeros((T, D), cd).at[token_of].add(contrib * weights[:, None])
-    return out.reshape(B, S, D), aux
+    return jnp.zeros((T, D), cd).at[token_of].add(contrib * weights[:, None])
+
+
+def apply_moe(
+    cfg: ArchConfig,
+    p: dict,
+    x: jax.Array,                      # [B, S, D]
+    *,
+    expert_sharding=None,              # optional fn: buffer [E,C,D/F] -> constrained
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Returns (output [B,S,D], router aux loss scalar, routes to held
+    experts int32 scalar)."""
+    m = cfg.moe
+    B, S, D = x.shape
+    first, held = m.held_range
+    xf = x.reshape(B * S, D)
+    topk_p, topk_e, aux = route(cfg, p, xf)
+    local = topk_e - first
+    local = jnp.where((local >= 0) & (local < held), local, held).astype(jnp.int32)
+    if m.capacity_factor is None:
+        out = _dropless(p, xf, topk_p, local, held)
+    else:
+        out = _capacity(cfg, p, xf, topk_p, local, held, expert_sharding)
+    if m.n_shared_experts:
+        out = out + L.apply_mlp(cfg, p["shared"], xf)
+    n_local = jnp.sum(local < held, dtype=jnp.int32)
+    return out.reshape(B, S, D), aux, n_local

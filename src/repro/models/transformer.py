@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from . import attention as attn
 from . import layers as L
-from . import mamba2, moe as moe_mod, rwkv6
+from . import mamba2, mla, moe as moe_mod, rwkv6
 
 
 def _identity_ac(x, kind):  # default activation-sharding hook
@@ -58,63 +58,77 @@ def scan_blocks(body, carry, xs, unroll: bool = False):
 # --------------------------------------------------------------------------
 # Standard transformer block (dense / moe / vlm)
 # --------------------------------------------------------------------------
-def init_tf_block(cfg: ArchConfig, rng: jax.Array) -> dict:
+def init_tf_block(cfg: ArchConfig, rng: jax.Array, *, dense: bool = False) -> dict:
+    """One decoder block; ``dense`` gives it a dense MLP in a MoE model (a
+    leading dense layer)."""
     k1, k2 = jax.random.split(rng)
     p = {
         "ln1": L.init_norm(cfg, cfg.d_model),
-        "attn": attn.init_attention(cfg, k1),
+        "attn": mla.init_mla(cfg, k1) if cfg.mla else attn.init_attention(cfg, k1),
         "ln2": L.init_norm(cfg, cfg.d_model),
     }
-    if cfg.moe:
+    if cfg.moe and not dense:
         p["moe"] = moe_mod.init_moe(cfg, k2)
     else:
         p["mlp"] = L.init_mlp(cfg, k2, cfg.d_model, cfg.d_ff)
     return p
 
 
+def _ffn(cfg, p, h, ac, expert_sharding):
+    """The block's MLP or MoE: (output, aux loss, routes to held experts)."""
+    if "moe" in p:
+        es = expert_sharding or (lambda t: ac(t, "expert"))
+        return moe_mod.apply_moe(cfg, p["moe"], h, expert_sharding=es)
+    return L.apply_mlp(cfg, p["mlp"], h), jnp.float32(0), jnp.int32(0)
+
+
+def _attn_ctx(cfg, p, h, *, rope, window, return_state=False):
+    """Attention over the whole context; with ``return_state`` also the
+    layer's cache leaves [B, S, ...]: K/V, or MLA's latent and rotated key."""
+    if cfg.mla:
+        return mla.mla_ctx(cfg, p, h, rope=rope, return_state=return_state)
+    out = attn.attention_ctx(cfg, p, h, rope=rope, causal=True, window=window,
+                             return_kv=return_state)
+    if return_state:
+        out, (k, v) = out
+        return out, {"k": k, "v": v}
+    return out
+
+
 def apply_tf_block(cfg, p, x, *, rope, window, ac, expert_sharding=None):
     h = L.apply_norm(cfg, p["ln1"], x)
-    out = attn.attention_ctx(cfg, p["attn"], h, rope=rope, causal=True, window=window)
+    out = _attn_ctx(cfg, p["attn"], h, rope=rope, window=window)
     x = x + ac(out, "partial")
     x = ac(x, "hidden_mid")
     h = L.apply_norm(cfg, p["ln2"], x)
-    if cfg.moe:
-        es = expert_sharding or (lambda t: ac(t, "expert"))
-        y, aux = moe_mod.apply_moe(cfg, p["moe"], h, expert_sharding=es)
-    else:
-        y, aux = L.apply_mlp(cfg, p["mlp"], h), jnp.float32(0)
+    y, aux, _ = _ffn(cfg, p, h, ac, expert_sharding)
     x = ac(x + ac(y, "partial"), "hidden")
     return x, aux
 
 
 def apply_tf_block_prefill(cfg, p, x, *, rope, window, ac, expert_sharding=None):
-    """Like apply_tf_block but also returns this layer's K/V for the cache."""
+    """Like apply_tf_block but also returns this layer's cache state."""
     h = L.apply_norm(cfg, p["ln1"], x)
-    out, (k, v) = attn.attention_ctx(cfg, p["attn"], h, rope=rope, causal=True,
-                                     window=window, return_kv=True)
+    out, state = _attn_ctx(cfg, p["attn"], h, rope=rope, window=window, return_state=True)
     x = ac(x + out, "hidden_mid")
     h = L.apply_norm(cfg, p["ln2"], x)
-    if cfg.moe:
-        es = expert_sharding or (lambda t: ac(t, "expert"))
-        y, aux = moe_mod.apply_moe(cfg, p["moe"], h, expert_sharding=es)
-    else:
-        y, aux = L.apply_mlp(cfg, p["mlp"], h), jnp.float32(0)
-    return ac(x + y, "hidden"), aux, (k, v)
+    y, aux, _ = _ffn(cfg, p, h, ac, expert_sharding)
+    return ac(x + y, "hidden"), aux, state
 
 
 def apply_tf_block_decode(cfg, p, x, cache, pos, *, rope_fn, window, ac,
                           expert_sharding=None):
+    """-> (x, cache, routes to held experts)."""
     h = L.apply_norm(cfg, p["ln1"], x)
-    out, cache = attn.attention_decode(cfg, p["attn"], h, cache, pos,
-                                       rope_fn=rope_fn, window=window)
+    if cfg.mla:
+        out, cache = mla.mla_decode(cfg, p["attn"], h, cache, pos, rope_fn=rope_fn)
+    else:
+        out, cache = attn.attention_decode(cfg, p["attn"], h, cache, pos,
+                                           rope_fn=rope_fn, window=window)
     x = x + out
     h = L.apply_norm(cfg, p["ln2"], x)
-    if cfg.moe:
-        es = expert_sharding or (lambda t: ac(t, "expert"))
-        y, _ = moe_mod.apply_moe(cfg, p["moe"], h, expert_sharding=es)
-    else:
-        y = L.apply_mlp(cfg, p["mlp"], h)
-    return x + y, cache
+    y, _, n_local = _ffn(cfg, p, h, ac, expert_sharding)
+    return x + y, cache, n_local
 
 
 # --------------------------------------------------------------------------
@@ -123,7 +137,8 @@ def apply_tf_block_decode(cfg, p, x, cache, pos, *, rope_fn, window, ac,
 def make_rope(cfg: ArchConfig, positions: jax.Array):
     """positions: [B,S] (rope) or [3,B,S] (mrope).  Returns (cos, sin) or None."""
     if cfg.rope_kind == "rope":
-        return L.rope_angles(positions, cfg.head_dim, cfg.rope_theta)
+        dim = cfg.mla.qk_rope_head_dim if cfg.mla else cfg.head_dim
+        return L.rope_angles(positions, dim, cfg.rope_theta)
     if cfg.rope_kind == "mrope":
         if positions.ndim == 2:  # text-only: t=h=w
             positions = jnp.broadcast_to(positions[None], (3,) + positions.shape)
@@ -156,6 +171,9 @@ class Model:
     decode_step: Callable[..., Any]      # (params, batch, caches, ac=...)
     init_caches: Callable[..., Any]      # (batch_size, capacity)
     scan_info: dict                      # cost scopes: {"layer_trip": L, ...}
+    # decoder LMs: every layer's attention cache stacked [L, ...] -> the
+    # caches decode_step takes
+    caches_from_layers: Callable[..., Any] | None = None
 
     def loss(self, params, batch, ac=_identity_ac, unroll=False):
         logits, aux, _ = self.forward(params, batch, ac=ac, unroll=unroll)
@@ -190,11 +208,18 @@ def _stack_init(init_one: Callable, rng: jax.Array, n: int):
 # Dense / MoE / VLM decoder-only LM
 # ======================================================================
 def _build_decoder_lm(cfg: ArchConfig) -> Model:
+    """Decoder LM: ``first_dense_layers`` leading blocks with a dense MLP
+    (``dense_blocks``), then the scanned units (``blocks``).  Decode caches
+    are ``{"blocks": tuple(per_unit)}``, with ``"dense"`` beside it where
+    there are leading dense layers and ``"routes"`` (int32 [2]: every route
+    and the routes to held experts, summed over decode steps) in a MoE model."""
     pattern = cfg.layer_pattern or ("layer",)
     per_unit = len(pattern)
-    assert cfg.n_layers % per_unit == 0
-    trip = cfg.n_layers // per_unit
+    n_dense = cfg.first_dense_layers
+    assert (cfg.n_layers - n_dense) % per_unit == 0 and (n_dense == 0 or per_unit == 1)
+    trip = (cfg.n_layers - n_dense) // per_unit
     windows = _layer_windows(cfg)
+    init_cache = mla.init_mla_cache if cfg.mla else attn.init_attn_cache
 
     def init(rng):
         k_e, k_b, k_u = jax.random.split(rng, 3)
@@ -208,6 +233,9 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
             "blocks": _stack_init(init_unit, k_b, trip),
             "final_norm": L.init_norm(cfg, cfg.d_model),
         }
+        if n_dense:
+            p["dense_blocks"] = _stack_init(lambda k: (init_tf_block(cfg, k, dense=True),),
+                                            jax.random.fold_in(k_b, 1), n_dense)
         if not cfg.tie_embeddings:
             p["unembed"] = (jax.random.normal(k_u, (cfg.d_model, cfg.vocab),
                                               jnp.dtype(cfg.param_dtype)) * 0.02)
@@ -233,37 +261,59 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
 
         def unit(x, unit_params):
             aux = jnp.float32(0)
-            kvs = []
+            states = []
             for i in range(per_unit):
                 if want_cache:
-                    x, a, kv = apply_tf_block_prefill(
+                    x, a, st = apply_tf_block_prefill(
                         cfg, unit_params[i], x, rope=rope, window=windows[i], ac=ac)
-                    kvs.append(kv)
+                    states.append(st)
                 else:
                     x, a = apply_tf_block(cfg, unit_params[i], x,
                                           rope=rope, window=windows[i], ac=ac)
                 aux = aux + a
-            return x, aux, tuple(kvs)
+            return x, aux, tuple(states)
 
         unit_fn = jax.checkpoint(unit) if (remat and not want_cache) else unit
 
         def body(carry, unit_params):
             x, aux = carry
-            x, a, kvs = unit_fn(x, unit_params)
-            return (x, aux + a), kvs
+            x, a, states = unit_fn(x, unit_params)
+            return (x, aux + a), states
 
-        (x, aux), kvs = scan_blocks(body, (x, jnp.float32(0)), params["blocks"], unroll)
+        carry = (x, jnp.float32(0))
+        if n_dense:
+            carry, lead = scan_blocks(body, carry, params["dense_blocks"], unroll)
+        (x, aux), states = scan_blocks(body, carry, params["blocks"], unroll)
         x = L.apply_norm(cfg, params["final_norm"], x)
         logits = ac(_unembed_out(params, x), "logits")
         caches = None
         if want_cache:
-            caches = kvs  # tuple(per_unit) of (k,v) stacked [trip, B, S, KV, hd]
+            # tuple(per_unit) of state dicts, leaves [layers, B, S, ...]
+            caches = states
+            if n_dense:
+                caches = (jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                       lead[0], states[0]),)
         return logits, aux / trip, caches
 
     def init_caches(batch_size, capacity):
         caps = [min(w, capacity) if w else capacity for w in windows]
-        one = tuple(attn.init_attn_cache(cfg, batch_size, c) for c in caps)
-        return jax.tree.map(lambda a: jnp.broadcast_to(a, (trip,) + a.shape), one)
+        one = tuple(init_cache(cfg, batch_size, c) for c in caps)
+        out = {"blocks": jax.tree.map(lambda a: jnp.broadcast_to(a, (trip,) + a.shape), one)}
+        if n_dense:
+            out["dense"] = jax.tree.map(lambda a: jnp.broadcast_to(a, (n_dense,) + a.shape), one)
+        if cfg.moe:
+            out["routes"] = jnp.zeros((2,), jnp.int32)
+        return out
+
+    def caches_from_layers(layers):
+        assert per_unit == 1
+        out = {"blocks": (layers if not n_dense
+                          else jax.tree.map(lambda a: a[n_dense:], layers),)}
+        if n_dense:
+            out["dense"] = (jax.tree.map(lambda a: a[:n_dense], layers),)
+        if cfg.moe:
+            out["routes"] = jnp.zeros((2,), jnp.int32)
+        return out
 
     rope_fn = make_rope_fn(cfg)
 
@@ -271,22 +321,33 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
         x = L.embed_tokens(cfg, params["embed"], batch["tokens"])  # [B,1,D]
         pos = batch["pos"]
 
-        def body(x, scanned):
+        def body(carry, scanned):
+            x, n_local = carry
             unit_params, unit_cache = scanned
             new_caches = []
             for i in range(per_unit):
-                x, c = apply_tf_block_decode(cfg, unit_params[i], x, unit_cache[i],
-                                             pos, rope_fn=rope_fn, window=windows[i], ac=ac)
+                x, c, n = apply_tf_block_decode(cfg, unit_params[i], x, unit_cache[i],
+                                                pos, rope_fn=rope_fn, window=windows[i], ac=ac)
                 new_caches.append(c)
-            return x, tuple(new_caches)
+                n_local = n_local + n
+            return (x, n_local), tuple(new_caches)
 
-        x, caches = scan_blocks(body, x, (params["blocks"], caches), unroll)
+        carry, out = (x, jnp.int32(0)), {}
+        if n_dense:
+            carry, out["dense"] = scan_blocks(
+                body, carry, (params["dense_blocks"], caches["dense"]), unroll)
+        (x, n_local), out["blocks"] = scan_blocks(
+            body, carry, (params["blocks"], caches["blocks"]), unroll)
+        if cfg.moe:
+            n_routes = trip * per_unit * x.shape[0] * cfg.moe.top_k
+            out["routes"] = caches["routes"] + jnp.stack([jnp.int32(n_routes), n_local])
         x = L.apply_norm(cfg, params["final_norm"], x)
         logits = ac(_unembed_out(params, x), "logits")
-        return logits, caches
+        return logits, out
 
     return Model(cfg, init, forward, decode_step, init_caches,
-                 scan_info={"layer_trip": trip, "per_unit": per_unit})
+                 scan_info={"layer_trip": trip, "per_unit": per_unit},
+                 caches_from_layers=caches_from_layers)
 
 
 # ======================================================================

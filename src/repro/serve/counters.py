@@ -4,14 +4,18 @@ Plain integers, added once per call (never per token) at the layer
 boundaries of ``engine.py`` and ``host_store.py``:
 
 * ``kv.fetch.to_device_bytes``: bytes of every upload inside
-  ``HostKVStore.fetch``, which copies nothing back;
+  ``HostKVStore.fetch``, which copies nothing back (the cache state: K/V,
+  or multi-head latent attention's latent and rotated key);
 * ``kv.fetch.tokens``: context tokens fetched;
-* ``kv.pull.to_host_bytes``: K/V pulled to the host after prefill;
+* ``kv.pull.to_host_bytes``: cache state pulled to the host after prefill;
 * ``cache.build.to_device_bytes``: host arrays uploaded to build a miss's
   cache;
 * ``cache.rebuild.batches``: hit batches whose cache the device laid out
   from the fetched K/V;
-* ``decode.host_syncs``: device-to-host syncs in the decode loop.
+* ``decode.host_syncs``: device-to-host syncs in the decode loop;
+* ``moe.routes`` and ``moe.routes.local``: a MoE model's routes in decode
+  steps (every token's top-k, over every MoE layer) and those to the
+  experts this device holds, summed on the device and read once a batch.
 
 Take a snapshot before and after the work of interest and subtract:
 the totals are shared by every engine in the process.

@@ -3,10 +3,12 @@
 Serving flow (mirrors the paper's vLLM + KV-offload setup, §5.3):
 
 1. A request arrives with a context key.  On a HOST CACHE MISS the engine
-   runs prefill on device, emits the first token, and SAVES the paged KV to
-   the host store.  On a HIT it FETCHES the KV blocks to the device, where
-   one program lays out the decode cache, and emits the first token with a
-   single decode step — no prefill compute.  The fetch backend defaults to
+   runs prefill on device, emits the first token, and SAVES the paged cache
+   state to the host store: the model's own per-layer leaves with a token
+   axis, K and V or multi-head latent attention's latent and rotated key.
+   On a HIT it FETCHES those blocks to the device, where one program lays
+   out the decode cache, and emits the first token with a single decode
+   step — no prefill compute.  The fetch backend defaults to
    the CommBackend's ``kv_fetch_plan`` (latte: the optimized ``opt_b2b``
    command stream, DESIGN.md §7/§8; reference: per-block ``pcpy``); an
    explicit ``fetch_backend`` string overrides the plan.
@@ -20,12 +22,14 @@ finished); they describe whatever backend ran them.
 Each layer boundary opens a ``jax.profiler.TraceAnnotation``, which costs
 about a microsecond and records only while the profiler runs, on the clock
 of the device trace: ``serve.generate`` > ``serve.first_token`` (the TTFT
-interval) > ``serve.kv.fetch`` (``host_store.py``), ``serve.cache.build``
+interval; ``batch``, ``hit``, ``cache``: ``kv`` or ``latent``) >
+``serve.kv.fetch`` (``host_store.py``), ``serve.cache.build``
 (the dispatch of the rebuild program), ``serve.step.first`` on a hit,
 ``serve.prefill`` > ``serve.kv.pull`` on a miss; then ``serve.kv.save`` and
 ``serve.cache.build`` (miss), ``serve.first_logits`` and ``serve.decode``.
-No span opens per decoded token.  Byte, batch and sync totals go to
-``repro.serve.counters``.
+No span opens per decoded token.  Byte, batch, sync and route totals go
+to ``repro.serve.counters``; a MoE model's routes are summed on the device
+over a batch's decode steps and read once after the last.
 
 Concurrent-traffic serving (DESIGN.md §12): :class:`ServingSimulator` is
 the *modeled* counterpart for load studies — a continuous-batching loop
@@ -51,7 +55,7 @@ from repro.models import attention as attn_mod
 from repro.models.transformer import Model
 from . import counters
 from .host_store import FetchResult, HostKVStore
-from .kvcache import BLOCK_TOKENS, kv_to_blocks
+from .kvcache import BLOCK_TOKENS, to_blocks
 
 
 @dataclasses.dataclass
@@ -89,12 +93,12 @@ class ServeEngine:
         def prefill(p, b):
             return model.forward(p, b, want_cache=True, remat=False)
 
-        def rebuild(ks, vs, n_tokens, capacity):
-            # fetched [L, S', KV, hd] a context -> the cache _build_cache makes
-            k = jnp.stack(ks, axis=1)[:, :, :n_tokens]     # [L, B, S, KV, hd]
-            v = jnp.stack(vs, axis=1)[:, :, :n_tokens]
-            return jax.vmap(lambda kl, vl: attn_mod.prefill_cache(
-                model.cfg, kl, vl, capacity))(k, v)
+        def rebuild(states, n_tokens, capacity):
+            # fetched leaves [L, S', ...] a context -> the cache _build_cache makes
+            state = {name: jnp.stack([s[name] for s in states], axis=1)[:, :, :n_tokens]
+                     for name in states[0]}                    # [L, B, S, ...]
+            return model.caches_from_layers(jax.vmap(lambda st: attn_mod.prefill_cache(
+                model.cfg, st, capacity))(state))
 
         self._prefill_jit = jax.jit(prefill)
         self._decode_jit = jax.jit(model.decode_step)
@@ -102,38 +106,41 @@ class ServeEngine:
 
     # ----------------------------------------------------------- helpers ----
     def _prefill(self, prompts: jax.Array):
+        """-> (logits, the cache state on the host: leaves [L, B, S, ...])."""
         with jax.profiler.TraceAnnotation("serve.prefill"):
-            logits, _, kvs = self._prefill_jit(self.params, {"tokens": prompts})
-            (k, v), = kvs      # per_unit == 1
+            logits, _, states = self._prefill_jit(self.params, {"tokens": prompts})
+            state, = states      # per_unit == 1
             with jax.profiler.TraceAnnotation("serve.kv.pull"):
-                k, v = np.asarray(k), np.asarray(v)   # [L, B, S, KV, hd]
-        counters.add("kv.pull.to_host_bytes", k.nbytes + v.nbytes)
-        return logits, k, v
+                state = {name: np.asarray(a) for name, a in state.items()}
+        counters.add("kv.pull.to_host_bytes", sum(a.nbytes for a in state.values()))
+        return logits, state
 
-    def _build_cache(self, k: np.ndarray, v: np.ndarray, capacity: int):
-        """k/v [L, B, S, KV, hd] -> stacked decode cache at ``capacity``."""
-        L, B, S, KV, hd = k.shape
+    def _build_cache(self, state: dict[str, np.ndarray], capacity: int):
+        """Host state leaves [L, B, S, ...] -> the decode caches at ``capacity``."""
         cfg = self.model.cfg
+        n_layers = next(iter(state.values())).shape[0]
 
-        def one_layer(kl, vl):
-            return attn_mod.prefill_cache(cfg, jnp.asarray(kl), jnp.asarray(vl), capacity)
+        def one_layer(i):
+            return attn_mod.prefill_cache(
+                cfg, {name: jnp.asarray(a[i]) for name, a in state.items()}, capacity)
 
         with jax.profiler.TraceAnnotation("serve.cache.build"):
-            layers = [one_layer(k[i], v[i]) for i in range(L)]
-            stacked = jax.tree.map(lambda *a: jnp.stack(a), *layers)
-        counters.add("cache.build.to_device_bytes", k.nbytes + v.nbytes)
-        return (stacked,)   # per_unit tuple
+            layers = [one_layer(i) for i in range(n_layers)]
+            caches = self.model.caches_from_layers(
+                jax.tree.map(lambda *a: jnp.stack(a), *layers))
+        counters.add("cache.build.to_device_bytes", sum(a.nbytes for a in state.values()))
+        return caches
 
     def _rebuild_cache(self, fetched: Sequence[FetchResult], n_tokens: int,
                        capacity: int):
-        """The batch's fetched K/V, already on the device -> the stacked
-        decode cache at ``capacity``, laid out by one program on the device:
-        the same arrays ``_build_cache`` makes from the same K/V."""
+        """The batch's fetched state, already on the device -> the decode
+        caches at ``capacity``, laid out by one program on the device: the
+        same arrays ``_build_cache`` makes from the same state."""
         with jax.profiler.TraceAnnotation("serve.cache.build"):
-            stacked = self._rebuild_jit([r.k for r in fetched], [r.v for r in fetched],
-                                        n_tokens=n_tokens, capacity=capacity)
+            caches = self._rebuild_jit([r.state for r in fetched],
+                                       n_tokens=n_tokens, capacity=capacity)
         counters.add("cache.rebuild.batches", 1)
-        return (stacked,)   # per_unit tuple
+        return caches
 
     def _planned_backend(self, keys: Sequence[str]) -> str:
         """Fetch backend from the CommBackend's plan for these contexts
@@ -153,8 +160,10 @@ class ServeEngine:
         B, S = prompts.shape
         capacity = capacity or S + 64
         all_hit = all(k in self.store for k in keys)
+        cache_kind = "latent" if self.model.cfg.mla else "kv"
         t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("serve.first_token", batch=B, hit=all_hit):
+        with jax.profiler.TraceAnnotation("serve.first_token", batch=B, hit=all_hit,
+                                          cache=cache_kind):
             if all_hit:
                 if fetch_backend is None:
                     fetch_backend = self._planned_backend(keys)
@@ -171,7 +180,7 @@ class ServeEngine:
                         cache)
                     first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             else:
-                logits, k, v = self._prefill(jnp.asarray(prompts))
+                logits, state = self._prefill(jnp.asarray(prompts))
                 first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
             wall = time.perf_counter() - t0
         if all_hit:
@@ -180,10 +189,10 @@ class ServeEngine:
             stats = []
             for b, key in enumerate(keys):
                 with jax.profiler.TraceAnnotation("serve.kv.save"):
-                    kb, vb = kv_to_blocks(k[:, b:b + 1], v[:, b:b + 1], self.block_tokens)
-                    self.store.save(key, kb, vb, S)
+                    self.store.save(key, {name: to_blocks(a[:, b:b + 1], self.block_tokens)
+                                          for name, a in state.items()}, S)
                 stats.append(RequestStats(key, False, wall, 0, S))
-            cache = self._build_cache(k, v, capacity)
+            cache = self._build_cache(state, capacity)
         with jax.profiler.TraceAnnotation("serve.first_logits"):
             first_logits = np.asarray(logits[:, -1], np.float32)
         return first, first_logits, cache, stats
@@ -206,6 +215,10 @@ class ServeEngine:
                     toks.append(np.asarray(cur)[:, 0])
                 dt = time.perf_counter() - t0
         counters.add("decode.host_syncs", n_new - 1)
+        if "routes" in cache:
+            routes, local = np.asarray(cache["routes"])
+            counters.add("moe.routes", routes)
+            counters.add("moe.routes.local", local)
         tokens = np.stack(toks, axis=1)
         return GenerationResult(tokens, first_logits, stats, dt,
                                 B * (n_new - 1) / max(dt, 1e-9))

@@ -1,12 +1,14 @@
 """Host-memory KV store for context caching (paper §5.3).
 
-KV for finished/parked contexts is SAVED to host memory (numpy — the "CPU
-DRAM tier") in paged blocks and FETCHED back on a cache hit instead of
-re-running prefill.  Three fetch backends mirror the paper's comparison:
+The cache state of finished/parked contexts is SAVED to host memory (numpy
+— the "CPU DRAM tier") in paged blocks and FETCHED back on a cache hit
+instead of re-running prefill.  A context's state is the model's own
+leaves, by name: ``k`` and ``v``, or multi-head latent attention's ``c``
+and ``k_pe``.  Three fetch backends mirror the paper's comparison:
 
 * ``pcpy``   — one transfer per block (baseline vLLM: one hipMemcpyAsync
                per dispersed block; here one ``jax.device_put`` each).
-* ``b2b``    — ONE batched transfer: a context's K and V blocks move with
+* ``b2b``    — ONE batched transfer: a context's leaves move with
                one launch + one sync (``hipMemcpyBatchAsync`` routed to one
                engine, §5.3.1); fan-out above the 4MB threshold.
 * ``opt_b2b``— the b2b data path under the name
@@ -18,10 +20,10 @@ re-running prefill.  Three fetch backends mirror the paper's comparison:
                (repro/kernels/paged_kv_gather) reassembles dispersed blocks
                on device (the CU/workgroup-per-block alternative).
 
-Every backend returns device arrays, K and V each ``[L, n_blocks * bt, KV,
-hd]`` (layer-major, as the decode cache is), and copies nothing back to the
+Every backend returns device arrays, each leaf ``[L, n_blocks * bt, ...]``
+(layer-major, as the decode cache is), and copies nothing back to the
 host: each saved byte crosses the host link once.  ``b2b`` reads the saved
-blocks in the order their bytes lie in memory (for ``kv_to_blocks``'s views
+blocks in the order their bytes lie in memory (for ``to_blocks``'s views
 of a C-ordered batch, layer-major), and every layout change runs on the
 device.  A fetch opens the profiler span ``serve.kv.fetch`` (with the
 context ``key``), and one ``serve.kv.fetch.h2d`` around each upload inside
@@ -44,8 +46,7 @@ from .kvcache import BLOCK_TOKENS, layer_major
 
 @dataclasses.dataclass
 class FetchResult:
-    k: jax.Array                # [L, n_blocks * bt, KV, hd], on the device
-    v: jax.Array
+    state: dict[str, jax.Array]   # by leaf: [L, n_blocks * bt, ...], on the device
     n_transfers: int
 
 
@@ -65,49 +66,52 @@ def _in_memory_order(a: np.ndarray) -> tuple[np.ndarray, tuple[int, ...]]:
     return t.reshape(shape), order
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "k_order", "v_order"))
-def _layer_major(k: jax.Array, v: jax.Array, shape: tuple[int, ...],
-                 k_order=(0, 1, 2, 3, 4), v_order=(0, 1, 2, 3, 4)):
-    """Device blocks of ``shape`` [n_blocks, bt, L, KV, hd], as uploaded
-    with their axes in ``*_order`` (and merged where they lay contiguously)
-    -> layer-major [L, n_blocks * bt, KV, hd], K and V at once."""
-    def one(x, order):
+@functools.partial(jax.jit, static_argnames=("shapes", "orders"))
+def _layer_major(arrays: tuple, shapes: tuple, orders: tuple | None = None):
+    """Device blocks, leaf i of ``shapes[i]`` [n_blocks, bt, L, ...], as
+    uploaded with their axes in ``orders[i]`` (and merged where they lay
+    contiguously; None: as saved) -> layer-major [L, n_blocks * bt, ...]."""
+    orders = orders or tuple(tuple(range(len(s))) for s in shapes)
+    out = []
+    for x, shape, order in zip(arrays, shapes, orders):
         x = x.reshape([shape[i] for i in order])
-        return layer_major(x.transpose(tuple(int(i) for i in np.argsort(order))))
-    return one(k, k_order), one(v, v_order)
+        out.append(layer_major(x.transpose(tuple(int(i) for i in np.argsort(order)))))
+    return tuple(out)
 
 
 class HostKVStore:
     def __init__(self, block_tokens: int = BLOCK_TOKENS):
         self.block_tokens = block_tokens
-        self._store: dict[str, tuple[np.ndarray, np.ndarray, int]] = {}
+        self._store: dict[str, tuple[dict[str, np.ndarray], int]] = {}
 
     # ------------------------------------------------------------- save ----
-    def save(self, key: str, k_blocks: np.ndarray, v_blocks: np.ndarray,
-             n_tokens: int) -> None:
-        self._store[key] = (np.asarray(k_blocks), np.asarray(v_blocks), n_tokens)
+    def save(self, key: str, blocks: dict[str, np.ndarray], n_tokens: int) -> None:
+        """Keep a context's state: each leaf's blocks [n_blocks, bt, L, ...]."""
+        self._store[key] = ({name: np.asarray(b) for name, b in blocks.items()}, n_tokens)
 
     def __contains__(self, key: str) -> bool:
         return key in self._store
 
-    def saved(self, key: str) -> tuple[np.ndarray, np.ndarray]:
-        """The (k_blocks, v_blocks) host arrays saved under ``key``."""
-        kb, vb, _ = self._store[key]
-        return kb, vb
+    def saved(self, key: str) -> dict[str, np.ndarray]:
+        """The host blocks saved under ``key``, by leaf."""
+        return self._store[key][0]
 
     def tokens_for(self, key: str) -> int:
-        return self._store[key][2]
+        return self._store[key][1]
 
     def blocks_for(self, key: str) -> tuple[int, int]:
-        """(n_blocks, bytes per K+V block) of a stored context — the inputs
-        ``CommBackend.kv_fetch_plan`` needs to plan the fetch."""
-        kb, vb, _ = self._store[key]
-        return kb.shape[0], kb[0].nbytes + vb[0].nbytes
+        """(n_blocks, bytes per block over every leaf) of a stored context —
+        the inputs ``CommBackend.kv_fetch_plan`` needs to plan the fetch."""
+        blocks = self.saved(key)
+        return next(iter(blocks.values())).shape[0], sum(b[0].nbytes for b in blocks.values())
 
     # ------------------------------------------------------------ fetch ----
     def fetch(self, key: str, backend: str = "b2b") -> FetchResult:
-        kb, vb, n_tokens = self._store[key]
-        n_blocks = kb.shape[0]
+        blocks, n_tokens = self._store[key]
+        names = tuple(blocks)
+        saved = tuple(blocks[n] for n in names)
+        shapes = tuple(b.shape for b in saved)
+        n_blocks = shapes[0][0]
         moved = 0
 
         def up(x):
@@ -121,15 +125,15 @@ class HostKVStore:
         with jax.profiler.TraceAnnotation("serve.kv.fetch", key=key):
             if backend == "pcpy":
                 # one device_put per dispersed block — per-copy launch + sync
-                k_blocks = [up(kb[i]) for i in range(n_blocks)]
-                v_blocks = [up(vb[i]) for i in range(n_blocks)]
-                k, v = _layer_major(jnp.stack(k_blocks), jnp.stack(v_blocks), kb.shape)
-                n_transfers = 2 * n_blocks
+                out = _layer_major(tuple(jnp.stack([up(b[i]) for i in range(n_blocks)])
+                                         for b in saved), shapes)
+                n_transfers = len(saved) * n_blocks
             elif backend in ("b2b", "opt_b2b"):
-                # ONE transfer and one sync for K and V, each read in the
+                # ONE transfer and one sync for every leaf, each read in the
                 # order of its memory; the device puts the axes back
-                (ks, ko), (vs, vo) = _in_memory_order(kb), _in_memory_order(vb)
-                k, v = _layer_major(*up((ks, vs)), kb.shape, k_order=ko, v_order=vo)
+                views = [_in_memory_order(b) for b in saved]
+                out = _layer_major(up(tuple(v for v, _ in views)), shapes,
+                                   tuple(o for _, o in views))
                 n_transfers = 1
             elif backend == "kernel":
                 # move the pool once; Pallas kernel gathers dispersed blocks.
@@ -137,15 +141,13 @@ class HostKVStore:
                 # test suite) runs it through the Pallas interpreter.
                 from repro.kernels.paged_kv_gather.ops import gather_blocks
                 interpret = jax.default_backend() == "cpu"
-                pool_k = up(kb.reshape(n_blocks, self.block_tokens, -1))
-                pool_v = up(vb.reshape(n_blocks, self.block_tokens, -1))
                 tbl = jnp.arange(n_blocks, dtype=jnp.int32)
-                k, v = _layer_major(gather_blocks(pool_k, tbl, interpret=interpret),
-                                    gather_blocks(pool_v, tbl, interpret=interpret),
-                                    kb.shape)
+                out = _layer_major(tuple(
+                    gather_blocks(up(b.reshape(n_blocks, self.block_tokens, -1)), tbl,
+                                  interpret=interpret) for b in saved), shapes)
                 n_transfers = 1
             else:
                 raise ValueError(backend)
         counters.add("kv.fetch.to_device_bytes", moved)
         counters.add("kv.fetch.tokens", n_tokens)
-        return FetchResult(k, v, n_transfers)
+        return FetchResult(dict(zip(names, out)), n_transfers)

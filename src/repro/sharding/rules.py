@@ -85,7 +85,8 @@ def infer_param_specs(params_shape: Any, cfg: ArchConfig, rules: ShardingRules) 
         shape = leaf.shape
         nd = len(shape)
         joined = "/".join(str(n) for n in names)
-        stacked = 1 if any(n in ("blocks", "supers", "enc_blocks", "dec_blocks") for n in names) else 0
+        stacked = 1 if any(n in ("blocks", "dense_blocks", "supers", "enc_blocks", "dec_blocks")
+                           for n in names) else 0
         # extra stacking inside zamba superblocks: params under supers are
         # [trip, ...] only (inner layers are tuple-indexed, not stacked).
         if nd - stacked == 0:
@@ -99,7 +100,8 @@ def infer_param_specs(params_shape: Any, cfg: ArchConfig, rules: ShardingRules) 
             spec = [None] * nd
             spec[-2] = rules._ok(shape[-2], rules.dp)
             return P(*spec)
-        if last in ("wg", "wu", "wd") and "moe" in joined:  # [.., E, D|F, F|D]
+        if last in ("wg", "wu", "wd") and "moe" in joined and "shared" not in names:
+            # routed experts [.., E, D|F, F|D] (shared experts: a plain MLP)
             # Expert-parallel when E divides the TP axis; otherwise TP the
             # expert-FFN hidden dim.  (Padded EP for E < axis is rejected by
             # jit argument shardings; hierarchical shard_map dispatch is the
@@ -115,8 +117,13 @@ def infer_param_specs(params_shape: Any, cfg: ArchConfig, rules: ShardingRules) 
                 spec[f_dim] = rules._ok(shape[f_dim], rules.tp)   # TP inside experts
                 spec[d_dim] = rules._ok(shape[d_dim], rules.dp)
             return P(*spec)
+        if last == "wkv_a":                            # MLA [.., D, latent + rope]
+            spec = [None] * nd                         # one latent for every head
+            spec[nd - 2] = rules._ok(shape[nd - 2], rules.dp)
+            return P(*spec)
         in_dim_names = {"wo", "wd", "w_out", "wB"}
-        out_dim_names = {"wq", "wk", "wv", "wg", "wu", "wA", "w_in", "wr"}
+        out_dim_names = {"wq", "wk", "wv", "wg", "wu", "wA", "w_in", "wr",
+                         "wkv_b"}                      # MLA's up-projection: per head
         if "cm" in names and last == "wv":              # rwkv channel-mix [F, D]
             return _in_dim_tp(rules, shape, stacked)
         if last in ("wq", "wk", "wv") and ("attn" in joined or "self_attn" in joined
@@ -224,7 +231,7 @@ def cache_specs(cache_tree: Any, cfg: ArchConfig, shape: ShapeConfig, rules: Sha
                 break
         if bdim is not None and batch_ok and shape.global_batch > 1:
             spec[bdim] = rules.dp
-        elif last in ("k", "v") and nd >= 3:
+        elif last in ("k", "v", "c", "k_pe") and nd >= 3:
             # batch==1: context parallelism — shard capacity over DP axes
             cap_dim = (bdim + 1) if bdim is not None else nd - 3
             if shape_[cap_dim] % _axsize(mesh, rules.dp) == 0:

@@ -30,7 +30,7 @@ from repro.core.dma.claims import (pipe_vs_final_chunk_ratio,
 from repro.core.dma.collectives import AR_AG_VARIANT, _pipe_granularity
 from repro.data.pipeline import DataConfig, synth_batch
 from repro.models.layers import apply_rotary, rope_angles
-from repro.serve.kvcache import kv_to_blocks, layer_major
+from repro.serve.kvcache import layer_major, to_blocks
 from repro.train.checkpoint import restore_checkpoint, save_checkpoint
 
 KB, MB = 1024, 1024 * 1024
@@ -287,7 +287,7 @@ def test_kv_block_roundtrip(s, kv, hd, layers, bt):
     rng = np.random.default_rng(0)
     k = rng.normal(size=(layers, 1, s, kv, hd)).astype(np.float32)
     v = rng.normal(size=(layers, 1, s, kv, hd)).astype(np.float32)
-    kb, vb = kv_to_blocks(k, v, bt)
+    kb, vb = to_blocks(k, bt), to_blocks(v, bt)
     # the host reference of what a fetch and rebuild undo: blocks -> [L, 1, S, KV, hd]
     k2, v2 = (layer_major(b)[:, :s][:, None] for b in (kb, vb))
     np.testing.assert_array_equal(k, k2)

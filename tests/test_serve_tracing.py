@@ -65,7 +65,7 @@ def served(tmp_path_factory):
             elif line.name not in ("python", "python3"):
                 programs.add(str(_stats(ev).get("hlo_module", "")))
     spans.sort(key=lambda t: (t[1], -t[2]))
-    saved = sum(a.nbytes for key in keys for a in eng.store.saved(key))
+    saved = sum(a.nbytes for key in keys for a in eng.store.saved(key).values())
     return dict(miss=miss, hit=hit, untraced=untraced, spans=spans, programs=programs,
                 miss_counts=_delta(c0, c1), hit_counts=_delta(c1, c2), saved=saved)
 
@@ -110,8 +110,8 @@ def test_spans_nest(served):
 def test_span_counts_per_batch(served):
     miss, hit = _batches(served)
     count = lambda spans, name: len(_named(spans, name))  # noqa: E731
-    assert [t[3] for t in _named(miss, "serve.first_token")] == [{"batch": B, "hit": 0}]
-    assert [t[3] for t in _named(hit, "serve.first_token")] == [{"batch": B, "hit": 1}]
+    assert [t[3] for t in _named(miss, "serve.first_token")] == [{"batch": B, "hit": 0, "cache": "kv"}]
+    assert [t[3] for t in _named(hit, "serve.first_token")] == [{"batch": B, "hit": 1, "cache": "kv"}]
     assert count(miss, "serve.kv.save") == B and count(miss, "serve.kv.fetch") == 0
     assert count(miss, "serve.prefill") == count(miss, "serve.kv.pull") == 1
     assert count(hit, "serve.kv.fetch") == B and count(hit, "serve.kv.save") == 0

@@ -29,12 +29,13 @@ def test_fetch_backends_bitwise_equal():
     rng = np.random.default_rng(0)
     kb = rng.normal(size=(5, 16, 2, 2, 16)).astype(np.float32)
     vb = rng.normal(size=(5, 16, 2, 2, 16)).astype(np.float32)
-    store.save("k", kb, vb, 70)
+    store.save("k", {"k": kb, "v": vb}, 70)
     res = {b: store.fetch("k", b) for b in BACKENDS}
     for b in BACKENDS:
-        assert isinstance(res[b].k, jax.Array) and isinstance(res[b].v, jax.Array)
-        np.testing.assert_array_equal(np.asarray(res[b].k), layer_major(store.saved("k")[0]))
-        np.testing.assert_array_equal(np.asarray(res[b].v), layer_major(store.saved("k")[1]))
+        for name in ("k", "v"):
+            assert isinstance(res[b].state[name], jax.Array)
+            np.testing.assert_array_equal(np.asarray(res[b].state[name]),
+                                          layer_major(store.saved("k")[name]))
     assert res["b2b"].n_transfers < res["pcpy"].n_transfers
     # the MI300X model of the same fetch: batching beats per-block copies,
     # and the optimized command stream only tightens the latency
@@ -61,18 +62,18 @@ def test_fetch_reads_any_stored_layout(memory_axes):
     """Saved views of a batch whose memory is in any order (a TPU hands a
     pulled batch back with its tokens minor-most) come back, from every
     backend, as the layer-major K/V that was saved."""
-    from repro.serve.kvcache import kv_to_blocks
+    from repro.serve.kvcache import to_blocks
 
     rng = np.random.default_rng(6)
     k, v = _batch(rng, memory_axes), _batch(rng, memory_axes)
     store = HostKVStore()
-    kb, vb = kv_to_blocks(k[:, 1:2], v[:, 1:2])
+    kb, vb = to_blocks(k[:, 1:2]), to_blocks(v[:, 1:2])
     assert np.shares_memory(kb, k)
-    store.save("ctx", kb, vb, 32)
+    store.save("ctx", {"k": kb, "v": vb}, 32)
     for b in BACKENDS:
         res = store.fetch("ctx", b)
-        np.testing.assert_array_equal(np.asarray(res.k), k[:, 1])
-        np.testing.assert_array_equal(np.asarray(res.v), v[:, 1])
+        np.testing.assert_array_equal(np.asarray(res.state["k"]), k[:, 1])
+        np.testing.assert_array_equal(np.asarray(res.state["v"]), v[:, 1])
 
 
 def test_engine_follows_kv_fetch_plan():
@@ -82,7 +83,7 @@ def test_engine_follows_kv_fetch_plan():
     rng = np.random.default_rng(3)
     kb = rng.normal(size=(4, 16, 2, 2, 16)).astype(np.float32)
     vb = rng.normal(size=(4, 16, 2, 2, 16)).astype(np.float32)
-    store.save("ctx", kb, vb, 60)
+    store.save("ctx", {"k": kb, "v": vb}, 60)
     n_blocks, block_bytes = store.blocks_for("ctx")
     assert n_blocks == 4 and block_bytes == kb[0].nbytes + vb[0].nbytes
 
@@ -111,7 +112,7 @@ def test_generation_identical_across_backends(engine):
 
 
 def _cache_arrays(cache):
-    (c,) = cache
+    (c,) = cache["blocks"]
     return {name: c[name] for name in ("k", "v", "kpos")}
 
 
@@ -145,7 +146,7 @@ def test_hit_moves_each_saved_byte_once(engine, backend):
     prompts = np.random.default_rng(4).integers(0, cfg.vocab, (2, 32)).astype(np.int32)
     keys = [f"once-{backend}-{i}" for i in range(2)]
     eng.first_token(prompts, keys)
-    saved = sum(a.nbytes for k in keys for a in eng.store.saved(k))
+    saved = sum(a.nbytes for k in keys for a in eng.store.saved(k).values())
     c0 = counters()
     out = eng.first_token(prompts, keys, fetch_backend=backend)
     moved = {k: n - c0.get(k, 0) for k, n in counters().items()}

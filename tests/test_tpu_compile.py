@@ -7,6 +7,7 @@ times.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import dataclasses
 import os
 
 import pytest
@@ -120,3 +121,26 @@ def test_qwen2_decode_step_compiles_and_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
+
+
+def test_moonlight_share_decode_step_compiles_and_fits_one_chip(one_chip):
+    """The served decode step of one chip's share of Moonlight-16B-A3B under
+    8-way expert parallelism (8 of 64 experts held, an eighth of the
+    vocabulary), at its published widths: absorbed latent attention and
+    the dropless grouped matmuls, over a latent cache of 32 x 1153."""
+    full = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(full, vocab=full.vocab // 8,
+                              moe=dataclasses.replace(full.moe, held=(0, 8)))
+    model = build_model(cfg)
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(lambda: model.init_caches(32, 1024 + 128 + 1)))
+    batch = {"tokens": _sds((32, 1), jnp.int32, one_chip),
+             "pos": _sds((), jnp.int32, one_chip)}
+    compiled = jax.jit(model.decode_step).lower(params, batch, caches).compile()
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert 0 < used < HBM_BYTES, used
+    assert "ragged" in compiled.as_text()
