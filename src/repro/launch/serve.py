@@ -10,7 +10,9 @@ path's.  Times are host-clock wall times on the device JAX runs on; a
 warm-up pass on other keys compiles every program first.  Each batch also
 reports what ``repro.serve.counters`` counted while it ran: bytes moved
 each way by the fetch or the prefill K/V pull, bytes uploaded to build a
-miss's cache, hit caches laid out on the device, and host syncs in decode.
+miss's cache, hit caches laid out on the device, and host syncs in decode;
+in a MoE model also its decode routes, those to the held experts, and the
+decode steps whose expert layers took the dense form.
 
     python -m repro.launch.serve                      # qwen2-0.5b, 8 x 1024 tokens
     JAX_PLATFORMS=cpu python -m repro.launch.serve --reduced --batch 2 --ctx 64
@@ -98,6 +100,10 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
         f"{cn['kv.pull.to_host_bytes']} B, cache upload "
         f"{cn['cache.build.to_device_bytes']} B, "
         f"{cn['decode.host_syncs']} decode syncs")
+    if cfg.moe:
+        log(f"[miss/moe     ] {cn['moe.routes']} decode routes, {cn['moe.routes.local']} "
+            f"to held experts; {cn['moe.held_dense.steps']} decode steps through the "
+            f"dense expert form")
     hits = {}
     for b, (res, cn) in counted_hits.items():
         st = res.request_stats[0]

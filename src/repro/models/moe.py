@@ -1,5 +1,5 @@
-"""Mixture-of-Experts: routing, then a dropless grouped dispatch or a
-sort-based capacity dispatch, then the shared experts.
+"""Mixture-of-Experts: routing, then a dropless dispatch (grouped, or dense
+on few tokens) or a sort-based capacity dispatch, then the shared experts.
 
 Design notes (DESIGN.md §6/§7):
 * Routing: ``softmax`` (top-k of the softmax, renormalised) or DeepSeek-V3's
@@ -10,10 +10,26 @@ Design notes (DESIGN.md §6/§7):
   count), routes over all ``n_experts`` and computes its own experts' part
   of the result; routes to the others add nothing here (on one chip, with no
   exchange).  Its weights are [count, ...].
-* ``capacity_factor=None``: dropless.  Routes are sorted by held expert and
-  each expert's contiguous rows go through grouped matmuls
-  (``jax.lax.ragged_dot``), so every route is computed and no buffer is
-  padded to a capacity.
+* ``capacity_factor=None``: dropless, every route is computed, in one of
+  two forms picked by the call's static token count ``T`` (``held_dense``):
+  - grouped (``T`` above ``HELD_DENSE_TOKENS``: prefill, training): routes
+    are sorted by held expert and each expert's contiguous rows go through
+    grouped matmuls (``jax.lax.ragged_dot``), so only the held routes are
+    computed and no buffer is padded to a capacity.  On TPU a grouped
+    matmul is a custom call, which takes no fused operand: inside the
+    layer scan each expert stack's per-layer slice is first copied to a
+    buffer of its own, the held weights read and written once more before
+    the matmul reads them.
+  - dense (``T`` up to the bound: decode): every held expert on every
+    token as plain batched dots, each token's output weighted by its gate
+    for that expert (zero, by ``jnp.where``, where it did not pick it).
+    The dots read the stacked weights where they lie, the layer slice fused
+    into them.  Both forms read every held expert's weights once (at 32
+    tokens, 7.66 of 8 are expected to be touched); the dense form spends
+    ``T`` multiply-adds, ``2T`` FLOPs, on each 2-byte bf16 weight, so it
+    stays bound by the weights' bytes while ``T`` is below the chip's FLOPs
+    per HBM byte, 197 TFLOP/s / 819 GB/s ~ 240 on v5e.  The bound of 128
+    keeps it near half that.
 * ``capacity_factor`` set: dispatch is gather/scatter-based (argsort by
   expert id + per-expert capacity buffer), NOT a dense [T, E, C] one-hot
   einsum — so the compiled FLOPs equal the *active* expert FLOPs.  The
@@ -94,6 +110,32 @@ def route(cfg: ArchConfig, p: dict, xf: jax.Array):
     return topk_p, topk_e, aux
 
 
+HELD_DENSE_TOKENS = 128
+
+
+def held_dense(cfg: ArchConfig, n_tokens: int) -> bool:
+    """Whether a call of ``n_tokens`` tokens computes its held experts in
+    the dense form (module docstring): a dropless layer at a token count
+    below the chip's FLOPs per HBM byte, with room to spare."""
+    m = cfg.moe
+    return m is not None and m.capacity_factor is None and n_tokens <= HELD_DENSE_TOKENS
+
+
+def _held_dense(p: dict, xf, topk_p, local, held):
+    """Every held expert on every token, combined by each token's gate for
+    it; a token picks an expert at most once, so the gate is its one route
+    weight there."""
+    cd = xf.dtype
+    hit = local[None] == jnp.arange(held, dtype=local.dtype)[:, None, None]   # [E, T, K]
+    gate = jnp.sum(jnp.where(hit, topk_p[None], 0.0), axis=-1)                # [E, T]
+    h = (jax.nn.silu(jnp.einsum("td,edf->etf", xf, p["wg"].astype(cd)))
+         * jnp.einsum("td,edf->etf", xf, p["wu"].astype(cd)))
+    y = jnp.einsum("etf,efd->etd", h, p["wd"].astype(cd))                     # [E, T, D]
+    y = jnp.where(jnp.any(hit, axis=-1)[..., None], y, 0)   # an unrouted row's inf stays out
+    return jnp.einsum("etd,et->td", y, gate.astype(cd),
+                      preferred_element_type=jnp.float32).astype(cd)
+
+
 def _dropless(p: dict, xf, topk_p, local, held):
     """Every route to a held expert through grouped matmuls, rows sorted by
     expert; the others (``local == held``) sort last and add nothing."""
@@ -164,7 +206,9 @@ def apply_moe(
     topk_p, topk_e, aux = route(cfg, p, xf)
     local = topk_e - first
     local = jnp.where((local >= 0) & (local < held), local, held).astype(jnp.int32)
-    if m.capacity_factor is None:
+    if held_dense(cfg, B * S):
+        out = _held_dense(p, xf, topk_p, local, held)
+    elif m.capacity_factor is None:
         out = _dropless(p, xf, topk_p, local, held)
     else:
         out = _capacity(cfg, p, xf, topk_p, local, held, expert_sharding)

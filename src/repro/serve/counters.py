@@ -15,7 +15,11 @@ boundaries of ``engine.py`` and ``host_store.py``:
 * ``decode.host_syncs``: device-to-host syncs in the decode loop;
 * ``moe.routes`` and ``moe.routes.local``: a MoE model's routes in decode
   steps (every token's top-k, over every MoE layer) and those to the
-  experts this device holds, summed on the device and read once a batch.
+  experts this device holds, summed on the device and read once a batch;
+* ``moe.held_dense.steps``: decode steps (after the first token) whose
+  expert layers computed the held experts in the dense form
+  (``repro.models.moe.held_dense`` at the batch's tokens); 0 in a model
+  without a dropless expert layer.
 
 Take a snapshot before and after the work of interest and subtract:
 the totals are shared by every engine in the process.

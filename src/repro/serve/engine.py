@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.backend import CommBackend
-from repro.models import attention as attn_mod
+from repro.models import attention as attn_mod, moe as moe_mod
 from repro.models.transformer import Model
 from . import counters
 from .host_store import FetchResult, HostKVStore
@@ -215,6 +215,8 @@ class ServeEngine:
                     toks.append(np.asarray(cur)[:, 0])
                 dt = time.perf_counter() - t0
         counters.add("decode.host_syncs", n_new - 1)
+        counters.add("moe.held_dense.steps",
+                     n_new - 1 if moe_mod.held_dense(self.model.cfg, B) else 0)
         if "routes" in cache:
             routes, local = np.asarray(cache["routes"])
             counters.add("moe.routes", routes)

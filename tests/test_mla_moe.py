@@ -126,6 +126,54 @@ def test_dropless_layer_keeps_every_token_under_skew():
     assert not np.allclose(np.asarray(dropped), np.asarray(got), atol=1e-3)
 
 
+@pytest.mark.parametrize("skewed", [False, True], ids=["plain", "skewed"])
+@pytest.mark.parametrize("n_tokens", [32, 256], ids=["decode", "prefill"])
+def test_dropless_forms_match_the_per_expert_loop(n_tokens, skewed, monkeypatch):
+    """At a decode-size call (dense form) and a prefill-size one above the
+    bound (grouped form), with the plain router and with one that sends
+    every token to one held expert: the form the bound picks, and the other
+    one forced on the same tokens, each give the per-expert loop's output
+    and the same routes to held experts."""
+    cfg = dataclasses.replace(DRIVER.arch_config(SMALL), compute_dtype="float32")
+    assert moe_mod.held_dense(cfg, n_tokens) == (n_tokens == 32)
+    p = _moe_params(cfg, 11)
+    first, held = cfg.moe.held_range
+    if skewed:
+        p["bias"] = p["bias"].at[first].set(100.0)
+    x = jax.random.normal(jax.random.PRNGKey(12), (2, n_tokens // 2, SMALL["hidden_size"]))
+    _, e, _ = moe_mod.route(cfg, p, x.reshape(n_tokens, -1))
+    want = np.asarray(_dense_moe(cfg, p, x))
+    n_held = int(jnp.sum((e >= first) & (e < first + held)))
+    got, _, n_local = moe_mod.apply_moe(cfg, p, x)
+    monkeypatch.setattr(moe_mod, "HELD_DENSE_TOKENS", 0 if n_tokens == 32 else n_tokens)
+    assert moe_mod.held_dense(cfg, n_tokens) == (n_tokens != 32)
+    other, _, n_local_other = moe_mod.apply_moe(cfg, p, x)
+    for out in (got, other):
+        np.testing.assert_allclose(np.asarray(out), want, rtol=1e-5, atol=1e-5)
+    assert int(n_local) == int(n_local_other) == n_held > 0
+
+
+@pytest.mark.parametrize("n_tokens", [32, 256], ids=["decode", "prefill"])
+def test_an_unrouted_expert_that_overflows_adds_nothing(n_tokens):
+    """A held expert no token picks, with weights that overflow bf16 on
+    every row: in either form its rows stay out of the output, which is
+    finite and what the layer gives with that expert's weights zeroed."""
+    cfg = DRIVER.arch_config(dict(SMALL, compute_dtype="bfloat16"))
+    p = moe_mod.init_moe(cfg, jax.random.PRNGKey(13))
+    first = cfg.moe.held_range[0]
+    p["bias"] = p["bias"].at[first].set(-100.0)          # no token picks it
+    x = jax.random.normal(jax.random.PRNGKey(14), (2, n_tokens // 2, SMALL["hidden_size"]),
+                          jnp.bfloat16)
+    _, e, _ = moe_mod.route(cfg, p, x.reshape(n_tokens, -1))
+    assert not bool(jnp.any(e == first))
+    huge = dict(p, wg=p["wg"].at[0].set(1e30), wu=p["wu"].at[0].set(1e30))
+    zero = dict(p, **{k: p[k].at[0].set(0) for k in ("wg", "wu", "wd")})
+    got, _, _ = moe_mod.apply_moe(cfg, huge, x)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(moe_mod.apply_moe(cfg, zero, x)[0], np.float32))
+
+
 def test_selection_bias_moves_the_choice_not_the_weights():
     cfg = DRIVER.arch_config(SMALL)
     p = _moe_params(cfg, 5)
@@ -193,6 +241,7 @@ def test_host_kv_hit_serves_the_miss_tokens_and_stores_the_latent(backend):
     n_moe = c["num_hidden_layers"] - c["first_k_dense_replace"]
     assert routes == 5 * n_moe * 2 * c["num_experts_per_tok"]        # 5 decode steps
     assert 0 < local < routes
+    assert c1["moe.held_dense.steps"] - c0.get("moe.held_dense.steps", 0) == 5
     assert c2["moe.routes"] - c1["moe.routes"] == routes + n_moe * 2 * c["num_experts_per_tok"]
 
 

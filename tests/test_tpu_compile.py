@@ -9,6 +9,7 @@ process may load the TPU library, and every test worker imports this file.
 """
 import dataclasses
 import os
+import re
 
 import pytest
 
@@ -30,6 +31,8 @@ from repro.serve.kvcache import BLOCK_TOKENS, blocks_for_tokens
 QWEN = get_config("qwen2-0.5b")
 BATCH, CTX, NEW = 8, 1024, 16          # the served batch of chip_smoke.py
 HBM_BYTES = 16 * 10 ** 9               # one v5e chip
+# one layer's held experts of Moonlight's share: wg/wu, then wd
+HELD_SLICES = ("bf16[8,2048,1408]", "bf16[8,1408,2048]")
 
 
 @pytest.fixture(scope="module")
@@ -123,19 +126,75 @@ def test_qwen2_decode_step_compiles_and_fits_one_chip(one_chip):
     assert 0 < used < HBM_BYTES, used
 
 
-def test_moonlight_share_decode_step_compiles_and_fits_one_chip(one_chip):
-    """The served decode step of one chip's share of Moonlight-16B-A3B under
-    8-way expert parallelism (8 of 64 experts held, an eighth of the
-    vocabulary), at its published widths: absorbed latent attention and
-    the dropless grouped matmuls, over a latent cache of 32 x 1153."""
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _copied_held_slices(hlo: str) -> list[str]:
+    """The instructions that put a layer's held experts in a buffer of their
+    own: one outside any fusion whose result is such a slice, or a fusion
+    whose computations make one and hold no dot (which the TPU compiler
+    writes as a convolution) to read it in place."""
+    comps = _computations(hlo)
+    called = lambda line: re.findall(r"calls=%([\w.\-]+)", line)  # noqa: E731
+    fused = {c for lines in comps.values() for line in lines if " fusion(" in line
+             for c in called(line)}
+
+    def body(name):
+        return comps[name] + [x for line in comps[name] for c in called(line) for x in body(c)]
+
+    def makes_slice(line):
+        result = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [a-z][\w\-]*\(", line)
+        return bool(result) and any(s in result.group(1) for s in HELD_SLICES)
+
+    copies = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            if makes_slice(line):
+                copies.append(line)
+            elif " fusion(" in line:
+                inner = [x for c in called(line) for x in body(c)]
+                if (any(makes_slice(x) for x in inner)
+                        and not any(re.search(r" (convolution|dot)\(", x) for x in inner)):
+                    copies.append(line)
+    return copies
+
+
+def _moonlight_share(one_chip):
+    """One chip's share of Moonlight-16B-A3B under 8-way expert parallelism
+    (8 of 64 experts held, an eighth of the vocabulary) at its published
+    widths: the model and its parameters' shapes on the described chip."""
     full = get_config("moonlight-16b-a3b")
     cfg = dataclasses.replace(full, vocab=full.vocab // 8,
                               moe=dataclasses.replace(full.moe, held=(0, 8)))
     model = build_model(cfg)
-    on_chip = lambda t: jax.tree.map(  # noqa: E731
-        lambda a: _sds(a.shape, a.dtype, one_chip), t)
-    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    caches = on_chip(jax.eval_shape(lambda: model.init_caches(32, 1024 + 128 + 1)))
+    params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    return model, params
+
+
+def test_moonlight_share_decode_step_compiles_and_fits_one_chip(one_chip):
+    """The served decode step of Moonlight's one-chip share: absorbed latent
+    attention and the held experts in the dense form, over a latent cache of
+    32 x 1153.  The expert dots read each layer's slice of the stacked
+    weights in place: no grouped-matmul custom call, which would need the
+    slice copied to a buffer of its own first."""
+    model, params = _moonlight_share(one_chip)
+    caches = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                          jax.eval_shape(lambda: model.init_caches(32, 1024 + 128 + 1)))
     batch = {"tokens": _sds((32, 1), jnp.int32, one_chip),
              "pos": _sds((), jnp.int32, one_chip)}
     compiled = jax.jit(model.decode_step).lower(params, batch, caches).compile()
@@ -143,4 +202,18 @@ def test_moonlight_share_decode_step_compiles_and_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
+    hlo = compiled.as_text()
+    assert "while(" in hlo                                       # the layer scan
+    assert "ragged" not in hlo
+    copies = _copied_held_slices(hlo)
+    assert not copies, [line.strip()[:120] for line in copies]
+
+
+def test_moonlight_share_prefill_keeps_grouped_matmuls(one_chip):
+    """The same share's prefill at a small prompt, above the dense form's
+    token bound: the held routes go through the grouped matmuls."""
+    model, params = _moonlight_share(one_chip)
+    tokens = _sds((1, 256), jnp.int32, one_chip)
+    compiled = jax.jit(lambda p, t: model.forward(p, {"tokens": t}, want_cache=True,
+                                                  remat=False)).lower(params, tokens).compile()
     assert "ragged" in compiled.as_text()
