@@ -30,8 +30,8 @@ from repro.core.dma.faults import FaultPlan, Straggler
 from repro.core.dma.topology import (mi300x_cluster, mi300x_platform,
                                      tpu_v5e_multislice, tpu_v5e_pod)
 from repro.core.dma.trace import chrome_trace, write_chrome_trace
-from repro.serve.engine import ServingSimulator
-from repro.serve.workload import Request
+from repro.core.serving_model import ServingSimulator
+from repro.core.workload import Request
 
 from .common import MB, ClaimChecker, fmt_size
 

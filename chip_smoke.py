@@ -8,9 +8,8 @@ through their normal entry points, with their results checked.
 One chip: qwen2-0.5b at its published widths (24 layers, d_model 896, 14
 heads, 2 KV heads, d_ff 4864, vocab 151936) with seeded random weights
 serves 8 requests of 1024 prompt tokens and 16 new tokens, first as misses
-(prefill, then save to the host KV store) and then as hits through each
-fetch backend (pcpy, b2b, opt_b2b, kernel); see ``repro.launch.serve`` for
-the checks.  The paged decode-attention kernel is then checked against its
+(prefill, then save to the host KV store) and then as hits (one upload a
+context); see ``repro.launch.serve`` for the checks.  The paged decode-attention kernel is then checked against its
 reference at the model's KV widths.
 
 Four chips: the Pallas ring all-gather and all-to-all kernels, the ppermute

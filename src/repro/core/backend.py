@@ -374,12 +374,13 @@ class CommBackend:
         return self._dispatch("all_reduce", _AR_IMPL, x, axis_name, size)
 
     def kv_fetch_plan(self, n_blocks: int, block_bytes: int) -> dict:
-        """How the serving engine should fetch dispersed KV blocks (§5.3).
+        """How a modeled server should fetch dispersed KV blocks (§5.3).
 
         The latte plan additionally requests the optimized command stream
         (``optimized: True`` — batched submission + fused write+signal on
-        the batch's chunk commands, DESIGN.md §7/§8); the serving engine
-        maps it to the ``opt_b2b`` fetch backend.
+        the batch's chunk commands, DESIGN.md §7/§8);
+        ``serving_model.ServingSimulator`` maps it to the
+        ``opt_prelaunch_b2b`` fetch schedule.
         """
         total = n_blocks * block_bytes
         if self.kind == "reference":

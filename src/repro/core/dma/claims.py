@@ -566,7 +566,7 @@ def serving_workload(rate: float):
     burst_factor 10), 4096-token prompts (±25%), 4 output tokens, seed 7 —
     fetch-dominated serving where KV-fetch DMA traffic, not decode compute,
     is the bottleneck (the regime the paper's offload targets)."""
-    from repro.serve.workload import synthetic_workload
+    from repro.core.workload import synthetic_workload
 
     return synthetic_workload(100, rate, seed=7, kind="bursty",
                               prompt_tokens=4096, output_tokens=4,
@@ -576,7 +576,7 @@ def serving_workload(rate: float):
 def serving_report(rate: float, admission: str):
     """One point of the serving sweep: the canonical workload through the
     §12 continuous-batching loop under ``admission`` ("fifo"/"defer")."""
-    from repro.serve.engine import ServingConfig, ServingSimulator
+    from repro.core.serving_model import ServingConfig, ServingSimulator
 
     sim = ServingSimulator(ServingConfig(admission=admission))
     return sim.run(serving_workload(rate))
@@ -768,7 +768,7 @@ def serving_fault_report(rate: float, admission: str, faults=None):
     """One point of the degraded-mode serving comparison: the canonical
     workload through the §12 loop under ``admission``, with ``faults``
     threaded into every composed round (DESIGN.md §13.4)."""
-    from repro.serve.engine import ServingConfig, ServingSimulator
+    from repro.core.serving_model import ServingConfig, ServingSimulator
 
     sim = ServingSimulator(ServingConfig(admission=admission), faults=faults)
     return sim.run(serving_workload(rate))

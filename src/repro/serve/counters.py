@@ -8,10 +8,8 @@ boundaries of ``engine.py`` and ``host_store.py``:
   or multi-head latent attention's latent and rotated key);
 * ``kv.fetch.tokens``: context tokens fetched;
 * ``kv.pull.to_host_bytes``: cache state pulled to the host after prefill;
-* ``cache.build.to_device_bytes``: host arrays uploaded to build a miss's
-  cache;
-* ``cache.rebuild.batches``: hit batches whose cache the device laid out
-  from the fetched K/V;
+* ``cache.rebuild.batches``: batches whose decode cache the device laid
+  out, a hit's from its fetched state and a miss's from its prefill state;
 * ``decode.host_syncs``: device-to-host syncs in the decode loop;
 * ``moe.routes`` and ``moe.routes.local``: a MoE model's routes in decode
   steps (every token's top-k, over every MoE layer) and those to the

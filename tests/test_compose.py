@@ -238,7 +238,7 @@ def test_release_shift_translates_lone_schedule():
 # ------------------------------------------------------------------------ #
 
 def test_workload_generators_deterministic():
-    from repro.serve.workload import (bursty_arrivals, poisson_arrivals,
+    from repro.core.workload import (bursty_arrivals, poisson_arrivals,
                                       synthetic_workload)
     assert poisson_arrivals(100.0, 50, seed=3) == poisson_arrivals(
         100.0, 50, seed=3)
@@ -256,16 +256,16 @@ def test_workload_generators_deterministic():
 
 
 def test_workload_deterministic_across_processes():
-    code = ("from repro.serve.workload import poisson_arrivals; "
+    code = ("from repro.core.workload import poisson_arrivals; "
             "print(repr(poisson_arrivals(250.0, 8, seed=42)))")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                         text=True, check=True).stdout.strip()
-    from repro.serve.workload import poisson_arrivals
+    from repro.core.workload import poisson_arrivals
     assert out == repr(poisson_arrivals(250.0, 8, seed=42))
 
 
 def test_bursty_mean_rate_is_normalized():
-    from repro.serve.workload import bursty_arrivals
+    from repro.core.workload import bursty_arrivals
     arr = bursty_arrivals(200.0, 4000, seed=0)
     rate = len(arr) / arr[-1]
     assert rate == pytest.approx(200.0, rel=0.15)
@@ -283,8 +283,8 @@ def test_golden_serving_trace():
     equality is the right pin: any behavioral drift (event ordering, tag
     namespacing, fluid-progress accounting) shows up here first.
     """
-    from repro.serve.engine import ServingConfig, ServingSimulator
-    from repro.serve.workload import synthetic_workload
+    from repro.core.serving_model import ServingConfig, ServingSimulator
+    from repro.core.workload import synthetic_workload
     wl = synthetic_workload(6, 1800.0, seed=11, kind="bursty",
                             prompt_tokens=2048, output_tokens=2,
                             burst_factor=10.0, p_enter=0.4, p_exit=0.1)

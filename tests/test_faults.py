@@ -321,8 +321,8 @@ def test_run_composed_accepts_faults():
 
 
 def test_serving_simulator_accepts_faults():
-    from repro.serve.engine import ServingConfig, ServingSimulator
-    from repro.serve.workload import synthetic_workload
+    from repro.core.serving_model import ServingConfig, ServingSimulator
+    from repro.core.workload import synthetic_workload
 
     reqs = synthetic_workload(12, 500.0, seed=3)
     clean = ServingSimulator(ServingConfig()).run(reqs)

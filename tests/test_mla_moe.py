@@ -212,8 +212,7 @@ def test_expert_shares_add_up_to_the_uncut_layer():
     assert routes == x.shape[0] * x.shape[1] * whole.moe.top_k
 
 
-@pytest.mark.parametrize("backend", ["b2b", "pcpy"])
-def test_host_kv_hit_serves_the_miss_tokens_and_stores_the_latent(backend):
+def test_host_kv_hit_serves_the_miss_tokens_and_stores_the_latent():
     """A hit serves the tokens of the miss that saved its context; the store
     holds L x (latent + rotated key) x 2 bytes a token, and the fetch moves
     exactly that; decode counts every route and those to held experts."""
@@ -221,11 +220,11 @@ def test_host_kv_hit_serves_the_miss_tokens_and_stores_the_latent(backend):
     model, params = _model(c), REF.make_params(c, 9)
     eng = ServeEngine(model, params)
     prompts = _tokens(2, 32, seed=2)
-    keys = [f"mla-{backend}-{i}" for i in range(2)]
+    keys = [f"mla-{i}" for i in range(2)]
     c0 = counters()
     miss = eng.generate(prompts, keys, 6)
     c1 = counters()
-    hit = eng.generate(prompts, keys, 6, fetch_backend=backend)
+    hit = eng.generate(prompts, keys, 6)
     c2 = counters()
     assert hit.request_stats[0].cache_hit and not miss.request_stats[0].cache_hit
     np.testing.assert_array_equal(hit.tokens, miss.tokens)

@@ -136,9 +136,7 @@ def test_counters_count_the_bytes_moved(served):
     assert hit["kv.fetch.tokens"] == B * S
     assert miss.get("kv.fetch.tokens", 0) == 0
     assert miss["kv.pull.to_host_bytes"] == saved and hit["kv.pull.to_host_bytes"] == 0
-    assert miss["cache.build.to_device_bytes"] == saved
-    assert hit["cache.build.to_device_bytes"] == 0
-    assert hit["cache.rebuild.batches"] == 1 and miss.get("cache.rebuild.batches", 0) == 0
+    assert hit["cache.rebuild.batches"] == miss["cache.rebuild.batches"] == 1
     assert miss["decode.host_syncs"] == hit["decode.host_syncs"] == NEW - 1
     assert miss["moe.held_dense.steps"] == hit["moe.held_dense.steps"] == 0     # no experts
 

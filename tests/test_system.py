@@ -39,7 +39,7 @@ def test_serving_end_to_end():
     rng = np.random.default_rng(0)
     prompts = rng.integers(0, cfg.vocab, (2, 32)).astype(np.int32)
     miss = eng.generate(prompts, ["x", "y"], 5)
-    hit = eng.generate(prompts, ["x", "y"], 5, fetch_backend="b2b")
+    hit = eng.generate(prompts, ["x", "y"], 5)
     np.testing.assert_array_equal(miss.tokens, hit.tokens)
     assert hit.request_stats[0].cache_hit
 
