@@ -95,7 +95,8 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
     log(f"[miss] TTFT {st.ttft_wall_s * 1e3:.3f} ms, decode "
         f"{miss.tokens_per_s_wall:.1f} tok/s ({where}); cache state pulled to the host "
         f"{cn['kv.pull.to_host_bytes']} B, {cn['cache.rebuild.batches']} cache laid "
-        f"out on the device, {cn['decode.host_syncs']} decode syncs")
+        f"out on the device, {cn['decode.host_syncs']} decode syncs, "
+        f"{cn['decode.cache.in_place_steps']} decode steps on a donated cache")
     if cfg.moe:
         log(f"[miss/moe] {cn['moe.routes']} decode routes, {cn['moe.routes.local']} "
             f"to held experts; {cn['moe.held_dense.steps']} decode steps through the "
@@ -105,7 +106,8 @@ def run(cfg: ArchConfig, *, batch: int, ctx: int, new: int, seed: int = 0,
         f"{hit.tokens_per_s_wall:.1f} tok/s ({where}); fetch "
         f"{ch['kv.fetch.to_device_bytes']} B to the device and 0 B back, "
         f"{ch['cache.rebuild.batches']} cache laid out on the device, "
-        f"{ch['decode.host_syncs']} decode syncs")
+        f"{ch['decode.host_syncs']} decode syncs, "
+        f"{ch['decode.cache.in_place_steps']} decode steps on a donated cache")
 
     for key in keys:
         saved = eng.store.saved(key)

@@ -191,6 +191,41 @@ def prefill_cache(cfg: ArchConfig, state: dict, capacity: int) -> dict:
     return {**out, "kpos": kpos}
 
 
+def write_token(cache: dict, rows: dict, pos: jax.Array, at: tuple = ()) -> tuple[dict, dict]:
+    """One token into a decode cache: each leaf of ``rows`` ([B, 1, ...])
+    at slot ``pos % capacity``, and ``pos`` into ``kpos``, of the cache at
+    index ``at``: a layer of a stack whose leaves are [L, B, capacity, ...]
+    and [L, capacity], or ``()`` for one layer's cache.
+
+    -> (the cache with only the token's rows written, so that a donated or
+    carried stack is updated where it lies; the layer at ``at`` as the
+    attention reads it: the cache's layer as it was, with the token
+    selected in, which the attention dots read in place before the write.
+    Read after the write, the layer gave the v5e compiler the write's
+    layout to honour, and it copied every layer out for the dots.)"""
+    kpos = cache["kpos"]
+    cap = kpos.shape[-1]
+    slot = (pos % cap).astype(jnp.int32)
+    lead = tuple(jnp.asarray(i, jnp.int32) for i in at)
+    zero = jnp.int32(0)
+    layer = cache
+    for i in lead:
+        layer = {name: jax.lax.dynamic_index_in_dim(a, i, keepdims=False)
+                 for name, a in layer.items()}
+    here = jnp.arange(cap, dtype=jnp.int32) == slot
+    pos = pos.astype(jnp.int32)
+    read = {name: jnp.where(here.reshape((cap,) + (1,) * (r.ndim - 2)), r, layer[name])
+            for name, r in rows.items()}
+    read["kpos"] = jnp.where(here, pos, layer["kpos"])
+    out = {name: jax.lax.dynamic_update_slice(
+               cache[name], r.reshape((1,) * len(at) + r.shape),
+               lead + (zero, slot) + (zero,) * (r.ndim - 2))
+           for name, r in rows.items()}
+    out["kpos"] = jax.lax.dynamic_update_slice(kpos, pos.reshape((1,) * kpos.ndim),
+                                               lead + (slot,))
+    return out, read
+
+
 def attention_decode(
     cfg: ArchConfig,
     p: dict,
@@ -200,11 +235,12 @@ def attention_decode(
     *,
     rope_fn=None,          # positions -> (cos, sin) for a [B,1] position
     window: int | None = None,
+    at: tuple = (),        # the layer of a stacked cache (``write_token``)
 ) -> tuple[jax.Array, dict]:
-    """Single-token decode against a (rolling) KV cache."""
+    """Single-token decode against a (rolling) KV cache; returns the
+    cache with the token written (``write_token``)."""
     B, _, D = x.shape
     H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    cap = cache["k"].shape[1]
     q, k_new, v_new = _project_qkv(cfg, p, x)
     if rope_fn is not None:
         pos_b = jnp.broadcast_to(pos, (B, 1))
@@ -213,10 +249,8 @@ def attention_decode(
         q = qf.reshape(B, 1, KV, H // KV, hd)
         k_new = apply_rotary(k_new, cos_q, sin_q)
 
-    slot = (pos % cap).astype(jnp.int32)
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-    kpos = jax.lax.dynamic_update_slice(cache["kpos"], pos[None].astype(jnp.int32), (slot,))
+    cache, layer = write_token(cache, {"k": k_new, "v": v_new}, pos, at)
+    k, v, kpos = layer["k"], layer["v"], layer["kpos"]
 
     scores = jnp.einsum("bckgd,bskd->bkgcs", q, k) / np.sqrt(hd)
     scores = softcap(scores, cfg.attn_softcap)
@@ -227,4 +261,4 @@ def attention_decode(
     w = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
     out = jnp.einsum("bkgcs,bskd->bckgd", w, v)
     out = out.reshape(B, 1, H * hd) @ p["wo"].astype(x.dtype)
-    return out, {"k": k, "v": v, "kpos": kpos}
+    return out, cache

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from . import layers as L
+from .attention import write_token
 
 # Prefill attends in query blocks of this many positions (when they divide
 # the prompt), so the score block is [B, H, Q_CHUNK, S], not [B, H, S, S].
@@ -117,17 +118,17 @@ def init_mla_cache(cfg: ArchConfig, batch: int, capacity: int) -> dict:
 
 
 def mla_decode(cfg: ArchConfig, p: dict, x: jax.Array, cache: dict, pos: jax.Array, *,
-               rope_fn) -> tuple[jax.Array, dict]:
+               rope_fn, at: tuple = ()) -> tuple[jax.Array, dict]:
     """One new token [B, 1, D] at position ``pos`` against the latent cache,
-    with ``W_uk`` absorbed into the query and ``W_uv`` into the output."""
+    with ``W_uk`` absorbed into the query and ``W_uv`` into the output;
+    returns the cache with the token written (``write_token``, ``at`` the
+    layer of a stacked cache)."""
     a = cfg.mla
     B = x.shape[0]
     cd = x.dtype
     q_nope, q_pe, c_new, kpe_new = _project(cfg, p, x, rope_fn(jnp.broadcast_to(pos, (B, 1))))
-    slot = (pos % cache["c"].shape[1]).astype(jnp.int32)
-    c = jax.lax.dynamic_update_slice(cache["c"], c_new, (0, slot, 0))
-    k_pe = jax.lax.dynamic_update_slice(cache["k_pe"], kpe_new, (0, slot, 0))
-    kpos = jax.lax.dynamic_update_slice(cache["kpos"], pos[None].astype(jnp.int32), (slot,))
+    cache, layer = write_token(cache, {"c": c_new, "k_pe": kpe_new}, pos, at)
+    c, k_pe, kpos = layer["c"], layer["k_pe"], layer["kpos"]
 
     wkv_b = p["wkv_b"].astype(cd).reshape(a.kv_lora_rank, cfg.n_heads, -1)
     w_uk, w_uv = wkv_b[..., :a.qk_nope_head_dim], wkv_b[..., a.qk_nope_head_dim:]
@@ -139,4 +140,4 @@ def mla_decode(cfg: ArchConfig, p: dict, x: jax.Array, cache: dict, pos: jax.Arr
     w = jax.nn.softmax(s, axis=-1).astype(cd)
     o_lat = jnp.einsum("bhqs,bsr->bqhr", w, c)
     out = jnp.einsum("bqhr,rhv->bqhv", o_lat, w_uv).reshape(B, 1, -1) @ p["wo"].astype(cd)
-    return out, {"c": c, "k_pe": k_pe, "kpos": kpos}
+    return out, cache

@@ -116,15 +116,18 @@ def apply_tf_block_prefill(cfg, p, x, *, rope, window, ac, expert_sharding=None)
     return ac(x + y, "hidden"), aux, state
 
 
-def apply_tf_block_decode(cfg, p, x, cache, pos, *, rope_fn, window, ac,
+def apply_tf_block_decode(cfg, p, x, cache, layer, pos, *, rope_fn, window, ac,
                           expert_sharding=None):
-    """-> (x, cache, routes to held experts)."""
+    """One token through the block whose cache is ``layer`` of the stacked
+    ``cache`` -> (x, the stack with the token written, routes to held
+    experts)."""
     h = L.apply_norm(cfg, p["ln1"], x)
     if cfg.mla:
-        out, cache = mla.mla_decode(cfg, p["attn"], h, cache, pos, rope_fn=rope_fn)
+        out, cache = mla.mla_decode(cfg, p["attn"], h, cache, pos, rope_fn=rope_fn,
+                                    at=(layer,))
     else:
         out, cache = attn.attention_decode(cfg, p["attn"], h, cache, pos,
-                                           rope_fn=rope_fn, window=window)
+                                           rope_fn=rope_fn, window=window, at=(layer,))
     x = x + out
     h = L.apply_norm(cfg, p["ln2"], x)
     y, _, n_local = _ffn(cfg, p, h, ac, expert_sharding)
@@ -318,26 +321,29 @@ def _build_decoder_lm(cfg: ArchConfig) -> Model:
     rope_fn = make_rope_fn(cfg)
 
     def decode_step(params, batch, caches, ac=_identity_ac, unroll=False):
+        """The stacked caches ride the layer scan's carry, and each layer
+        writes only the new token's rows into them: with ``caches`` donated,
+        the step updates the cache where it lies."""
         x = L.embed_tokens(cfg, params["embed"], batch["tokens"])  # [B,1,D]
         pos = batch["pos"]
 
         def body(carry, scanned):
-            x, n_local = carry
-            unit_params, unit_cache = scanned
-            new_caches = []
+            x, n_local, stack = carry
+            unit_params, layer = scanned
+            new_stack = []
             for i in range(per_unit):
-                x, c, n = apply_tf_block_decode(cfg, unit_params[i], x, unit_cache[i],
+                x, c, n = apply_tf_block_decode(cfg, unit_params[i], x, stack[i], layer,
                                                 pos, rope_fn=rope_fn, window=windows[i], ac=ac)
-                new_caches.append(c)
+                new_stack.append(c)
                 n_local = n_local + n
-            return (x, n_local), tuple(new_caches)
+            return (x, n_local, tuple(new_stack)), None
 
-        carry, out = (x, jnp.int32(0)), {}
-        if n_dense:
-            carry, out["dense"] = scan_blocks(
-                body, carry, (params["dense_blocks"], caches["dense"]), unroll)
-        (x, n_local), out["blocks"] = scan_blocks(
-            body, carry, (params["blocks"], caches["blocks"]), unroll)
+        n_local, out = jnp.int32(0), {}
+        for name, blocks, n in (("dense", "dense_blocks", n_dense), ("blocks", "blocks", trip)):
+            if n:
+                (x, n_local, out[name]), _ = scan_blocks(
+                    body, (x, n_local, caches[name]),
+                    (params[blocks], jnp.arange(n, dtype=jnp.int32)), unroll)
         if cfg.moe:
             n_routes = trip * per_unit * x.shape[0] * cfg.moe.top_k
             out["routes"] = caches["routes"] + jnp.stack([jnp.int32(n_routes), n_local])

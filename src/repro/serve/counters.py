@@ -11,6 +11,10 @@ boundaries of ``engine.py`` and ``host_store.py``:
 * ``cache.rebuild.batches``: batches whose decode cache the device laid
   out, a hit's from its fetched state and a miss's from its prefill state;
 * ``decode.host_syncs``: device-to-host syncs in the decode loop;
+* ``decode.cache.in_place_steps``: decode steps, ``step.first`` on a hit
+  included, whose input cache the step took by donation (its buffers
+  deleted after the call), so that the step updated the cache in place;
+  0 where the backend refused the donation;
 * ``moe.routes`` and ``moe.routes.local``: a MoE model's routes in decode
   steps (every token's top-k, over every MoE layer) and those to the
   experts this device holds, summed on the device and read once a batch;

@@ -26,9 +26,11 @@ interval; ``batch``, ``hit``, ``cache``: ``kv`` or ``latent``) >
 (the dispatch of the rebuild program), ``serve.step.first`` on a hit,
 ``serve.prefill`` > ``serve.kv.pull`` on a miss; then ``serve.kv.save`` and
 ``serve.cache.build`` (miss), ``serve.first_logits`` and ``serve.decode``.
-No span opens per decoded token.  Byte, batch, sync and route totals go
-to ``repro.serve.counters``; a MoE model's routes are summed on the device
-over a batch's decode steps and read once after the last.
+No span opens per decoded token.  The decode step takes its cache by
+donation and writes each token where the cache lies.  Byte, batch, sync,
+in-place step and route totals go to ``repro.serve.counters``; a MoE
+model's routes are summed on the device over a batch's decode steps and
+read once after the last.
 
 The modeled MI300X server for load studies is ``ServingSimulator`` in
 ``core/serving_model.py`` (DESIGN.md §12).
@@ -49,6 +51,24 @@ from repro.models.transformer import Model
 from . import counters
 from .host_store import HostKVStore
 from .kvcache import to_blocks
+
+
+# Decode caches hold whole tiles of 8 slots, the TPU's sublane tile.  The v5e
+# lays out a latent cache [L, B, capacity, R] with its slots down the tiles
+# only where they fill them, and across the batch otherwise; the attention
+# dots read a layer in place only in the first layout.
+SLOT_TILE = 8
+
+
+def decode_capacity(slots: int) -> int:
+    """The capacity of the decode caches the engine lays out for ``slots``
+    slots: whole tiles of ``SLOT_TILE``."""
+    return -(-slots // SLOT_TILE) * SLOT_TILE
+
+
+def _consumed(cache) -> int:
+    """1 if a decode step took ``cache``'s buffers by donation, else 0."""
+    return int(jax.tree.leaves(cache)[0].is_deleted())
 
 
 @dataclasses.dataclass
@@ -94,7 +114,9 @@ class ServeEngine:
                 model.cfg, st, capacity))(state))
 
         self._prefill_jit = jax.jit(prefill)
-        self._decode_jit = jax.jit(model.decode_step)
+        # the step writes one token into the cache it is given: donated,
+        # its output is the input's own buffers
+        self._decode_jit = jax.jit(model.decode_step, donate_argnames="caches")
         self._rebuild_jit = jax.jit(rebuild, static_argnames=("n_tokens", "capacity"))
 
     # ----------------------------------------------------------- helpers ----
@@ -123,10 +145,11 @@ class ServeEngine:
     # ------------------------------------------------------------ public ----
     def first_token(self, prompts: np.ndarray, keys: Sequence[str],
                     *, capacity: int | None = None):
-        """TTFT path for a batch sharing prompt length.  Returns
+        """TTFT path for a batch sharing prompt length, with a decode cache
+        of at least ``capacity`` slots (``decode_capacity``).  Returns
         (first_tokens [B], first_logits [B, vocab] f32, cache, stats)."""
         B, S = prompts.shape
-        capacity = capacity or S + 64
+        capacity = decode_capacity(capacity or S + 64)
         all_hit = all(k in self.store for k in keys)
         cache_kind = "latent" if self.model.cfg.mla else "kv"
         t0 = time.perf_counter()
@@ -137,13 +160,14 @@ class ServeEngine:
                 if len(n_tokens) != 1:
                     raise ValueError(f"a hit batch's contexts differ in length: {n_tokens}")
                 fetched = [self.store.fetch(key) for key in keys]
-                cache = self._rebuild_cache(fetched, n_tokens.pop(), capacity)
+                built = self._rebuild_cache(fetched, n_tokens.pop(), capacity)
                 with jax.profiler.TraceAnnotation("serve.step.first"):
                     logits, cache = self._decode_jit(
                         self.params,
                         {"tokens": jnp.asarray(prompts[:, -1:]), "pos": jnp.int32(S - 1)},
-                        cache)
+                        built)
                     first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+                counters.add("decode.cache.in_place_steps", _consumed(built))
             else:
                 logits, state, pulled = self._prefill(jnp.asarray(prompts))
                 first = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
@@ -168,15 +192,19 @@ class ServeEngine:
                 prompts, keys, capacity=capacity)
             toks = [first]
             cur = jnp.asarray(first)[:, None]
+            in_place = 0
             with jax.profiler.TraceAnnotation("serve.decode"):
                 t0 = time.perf_counter()
                 for i in range(n_new - 1):
+                    prev = cache
                     logits, cache = self._decode_jit(
-                        self.params, {"tokens": cur, "pos": jnp.int32(S + i)}, cache)
+                        self.params, {"tokens": cur, "pos": jnp.int32(S + i)}, prev)
+                    in_place += _consumed(prev)
                     cur = jnp.argmax(logits[:, -1], axis=-1)[:, None]
                     toks.append(np.asarray(cur)[:, 0])
                 dt = time.perf_counter() - t0
         counters.add("decode.host_syncs", n_new - 1)
+        counters.add("decode.cache.in_place_steps", in_place)
         counters.add("moe.held_dense.steps",
                      n_new - 1 if moe_mod.held_dense(self.model.cfg, B) else 0)
         if "routes" in cache:

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.models import attention as attn_mod, build_model
 from repro.serve.counters import counters
+from repro.serve import engine as engine_mod
 from repro.serve.engine import ServeEngine
 from repro.serve.host_store import HostKVStore
 from repro.serve.kvcache import layer_major
@@ -127,6 +128,7 @@ def test_hit_cache_equals_miss_cache(engines, kind, source, S, capacity):
         0, vocab, (2, S)).astype(np.int32)
     keys = [f"cache-{kind}-{source}-{S}-{capacity}-{i}" for i in range(2)]
     *_, cache, _ = eng.first_token(prompts, keys, capacity=capacity)
+    capacity = engine_mod.decode_capacity(capacity)        # whole blocks of slots
     if source == "hit":
         cache = eng._rebuild_cache([eng.store.fetch(k) for k in keys], S, capacity)
     want = _layer_by_layer(eng, keys, S, capacity)
@@ -201,6 +203,67 @@ def test_second_hit_batch_compiles_nothing(engine):
     assert compiled == []
     assert eng._rebuild_jit._cache_size() == rebuild_programs
     assert eng._decode_jit._cache_size() == decode_programs
+
+
+def _plain_tokens(eng, prompts, keys, n_new, hit):
+    """What ``generate`` serves, with every decode step the un-jitted
+    ``model.decode_step`` on a cache of its own: (tokens, first_logits)."""
+    B, S = prompts.shape
+    capacity = engine_mod.decode_capacity(S + n_new + 1)
+    if hit:
+        cache = eng._rebuild_cache([eng.store.fetch(k) for k in keys], S, capacity)
+        logits, cache = eng.model.decode_step(
+            eng.params, {"tokens": jnp.asarray(prompts[:, -1:]), "pos": jnp.int32(S - 1)}, cache)
+    else:
+        logits, _, (state,) = eng._prefill_jit(eng.params, {"tokens": jnp.asarray(prompts)})
+        cache = eng._rebuild_cache([state], S, capacity)
+    first_logits = np.asarray(logits[:, -1], np.float32)
+    toks = [np.asarray(jnp.argmax(logits[:, -1], axis=-1))]
+    for i in range(n_new - 1):
+        logits, cache = eng.model.decode_step(
+            eng.params, {"tokens": jnp.asarray(toks[-1])[:, None], "pos": jnp.int32(S + i)},
+            cache)
+        toks.append(np.asarray(jnp.argmax(logits[:, -1], axis=-1)))
+    return np.stack(toks, axis=1), first_logits
+
+
+@pytest.mark.parametrize("kind,capacity", [("kv", None), ("latent", None), ("kv", 32)],
+                         ids=["gqa", "latent-dense-lead", "gqa-rolling"])
+def test_donated_step_serves_what_the_plain_step_serves(engines, kind, capacity,
+                                                        monkeypatch):
+    """``generate`` through the jitted step, which takes the cache by
+    donation and writes each token where the cache lies, decodes the
+    tokens and first logits of the un-jitted ``model.decode_step`` bit for
+    bit, on a miss and on a hit: for grouped-query K/V, for a latent split
+    into a leading dense layer and expert layers, and in a rolling cache
+    shorter than the context (32 slots for 40 + 6 tokens)."""
+    if capacity:
+        monkeypatch.setattr(engine_mod, "decode_capacity", lambda slots: capacity)
+    eng, vocab = engines[kind]
+    prompts = np.random.default_rng(7).integers(0, vocab, (2, 40)).astype(np.int32)
+    keys = [f"plain-{kind}-{capacity}-{i}" for i in range(2)]
+    for hit in (False, True):
+        res = eng.generate(prompts, keys, 6)
+        assert res.request_stats[0].cache_hit == hit
+        want_tokens, want_logits = _plain_tokens(eng, prompts, keys, 6, hit)
+        np.testing.assert_array_equal(res.tokens, want_tokens)
+        np.testing.assert_array_equal(res.first_logits, want_logits)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_every_decode_step_consumes_its_cache(engines, kind):
+    """``decode.cache.in_place_steps`` counts the decode calls whose input
+    cache the step took by donation: every one, ``step.first`` included,
+    so ``n_new`` on a hit and ``n_new - 1`` on a miss."""
+    eng, vocab = engines[kind]
+    prompts = np.random.default_rng(8).integers(0, vocab, (2, 24)).astype(np.int32)
+    keys = [f"in-place-{kind}-{i}" for i in range(2)]
+    for hit, steps in ((False, 4), (True, 5)):
+        c0 = counters()
+        res = eng.generate(prompts, keys, 5)
+        assert res.request_stats[0].cache_hit == hit
+        assert counters()["decode.cache.in_place_steps"] - c0.get(
+            "decode.cache.in_place_steps", 0) == steps
 
 
 def test_serve_entry_point_checks_pass_on_reduced_config():

@@ -26,6 +26,7 @@ from repro.kernels.ring_all_to_all.ops import VARIANTS as AA_VARIANTS
 from repro.kernels.ring_all_to_all.ops import pallas_all_to_all_fn
 from repro.launch.mesh import make_mesh
 from repro.models import build_model
+from repro.serve.engine import decode_capacity
 from repro.serve.kvcache import BLOCK_TOKENS, blocks_for_tokens
 
 QWEN = get_config("qwen2-0.5b")
@@ -141,28 +142,40 @@ def _computations(hlo: str) -> dict[str, list[str]]:
     return comps
 
 
-def _copied_held_slices(hlo: str) -> list[str]:
-    """The instructions that put a layer's held experts in a buffer of their
-    own: one outside any fusion whose result is such a slice, or a fusion
-    whose computations make one and hold no dot (which the TPU compiler
-    writes as a convolution) to read it in place."""
+def _copied_slices(hlo: str, slices, within=None) -> list[str]:
+    """The instructions that put one of ``slices`` (result shapes, e.g. one
+    layer's slice of a stacked array) in a buffer of its own: one outside
+    any fusion whose result is such a slice, or a fusion whose computations
+    make one and hold no dot (which the TPU compiler writes as a
+    convolution) to read it in place.  ``within``: look only in these
+    computations and those they call."""
     comps = _computations(hlo)
-    called = lambda line: re.findall(r"calls=%([\w.\-]+)", line)  # noqa: E731
+    called = lambda line: re.findall(  # noqa: E731
+        r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line)
     fused = {c for lines in comps.values() for line in lines if " fusion(" in line
              for c in called(line)}
 
     def body(name):
         return comps[name] + [x for line in comps[name] for c in called(line) for x in body(c)]
 
+    names = set(comps)
+    if within is not None:
+        names, todo = set(), list(within)
+        while todo:
+            name = todo.pop()
+            if name not in names:
+                names.add(name)
+                todo += [c for line in comps[name] for c in called(line)]
+
     def makes_slice(line):
         result = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) [a-z][\w\-]*\(", line)
-        return bool(result) and any(s in result.group(1) for s in HELD_SLICES)
+        return bool(result) and any(s in result.group(1) for s in slices)
 
     copies = []
-    for name, lines in comps.items():
+    for name in names:
         if name in fused:
             continue
-        for line in lines:
+        for line in comps[name]:
             if makes_slice(line):
                 copies.append(line)
             elif " fusion(" in line:
@@ -205,7 +218,7 @@ def test_moonlight_share_decode_step_compiles_and_fits_one_chip(one_chip):
     hlo = compiled.as_text()
     assert "while(" in hlo                                       # the layer scan
     assert "ragged" not in hlo
-    copies = _copied_held_slices(hlo)
+    copies = _copied_slices(hlo, HELD_SLICES)
     assert not copies, [line.strip()[:120] for line in copies]
 
 
@@ -217,3 +230,39 @@ def test_moonlight_share_prefill_keeps_grouped_matmuls(one_chip):
     compiled = jax.jit(lambda p, t: model.forward(p, {"tokens": t}, want_cache=True,
                                                   remat=False)).lower(params, tokens).compile()
     assert "ragged" in compiled.as_text()
+
+
+HLO_DTYPE = {"bfloat16": "bf16", "float32": "f32"}
+
+
+@pytest.mark.parametrize("cell", ["moonlight-share", "qwen2-0.5b"])
+def test_decode_step_updates_the_donated_cache_in_place(one_chip, cell):
+    """The served decode step as ``ServeEngine`` jits it, the cache donated,
+    at a cell's batch and cache (Moonlight's share: 32 sequences over a
+    latent cache; qwen2-0.5b: 16 over K/V; 1024 + 128 + 1 slots, as the
+    engine lays them out).  The layer scan carries the stacked cache and
+    writes only the new token into it: no instruction in its body makes a
+    whole layer of a cache leaf a buffer of its own (the dots read it in
+    place), and the output cache is the input's own buffers."""
+    if cell == "moonlight-share":
+        (model, params), batch = _moonlight_share(one_chip), 32
+    else:
+        model, batch = build_model(QWEN), 16
+        params = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                              jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), jax.eval_shape(
+        lambda: model.init_caches(batch, decode_capacity(1024 + 128 + 1))))
+    step = {"tokens": _sds((batch, 1), jnp.int32, one_chip),
+            "pos": _sds((), jnp.int32, one_chip)}
+    compiled = jax.jit(model.decode_step, donate_argnames="caches").lower(
+        params, step, caches).compile()
+    hlo = compiled.as_text()
+    layers = {f"{HLO_DTYPE[a.dtype.name]}[{','.join(map(str, shape))}]"
+              for a in jax.tree.leaves(caches) if a.ndim > 2          # not kpos, routes
+              for shape in (a.shape[1:], (1,) + a.shape[1:])}
+    scans = re.findall(r"body=%([\w.\-]+)", hlo)
+    assert scans                                                  # the layer scan
+    copies = _copied_slices(hlo, layers, within=scans)
+    assert not copies, [line.strip()[:120] for line in copies]
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(caches))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
